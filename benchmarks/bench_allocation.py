@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks import common
+from repro.compile_cache import enable_compile_cache
 from repro.core import auction, disba, network
 from repro.core.types import mask_inactive
 
@@ -322,6 +323,7 @@ def main() -> None:
     ap.add_argument("--out", default=DEFAULT_OUT,
                     help=f"output path (default: {DEFAULT_OUT} at repo root)")
     args = ap.parse_args()
+    enable_compile_cache()
     data = run(tiny=args.tiny)
     validate(data)
     with open(args.out, "w") as fp:

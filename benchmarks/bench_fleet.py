@@ -12,11 +12,16 @@ single-device ``run_batch`` vmap, scaled over forced-host device counts
 * **sharding** -- the seed axis splits across devices, so forced-host CPU
   devices (or real accelerators) add near-linear throughput on top.
 
-Every row is measured in a fresh worker subprocess so each device count gets
-its own ``XLA_FLAGS=--xla_force_host_platform_device_count`` before jax
-initializes (the flag is locked in at first device query).  The 8-device
-worker also checks per-seed *bitwise* parity of ``run_fleet`` against
-``run_batch``, records the max deviation (0.0 by construction), and runs
+On the CPU every row is measured in a fresh worker subprocess so each
+device count gets its own ``XLA_FLAGS=--xla_force_host_platform_device_count``
+before jax initializes (the flag is locked in at first device query); the
+parent never touches JAX there when ``JAX_PLATFORMS=cpu``.  On an
+accelerator a child could not reach the devices the parent holds, so every
+device count runs in-process over the first n devices
+(``launch.mesh.make_fleet_mesh``), up to as many as the host has.  The
+largest device count (8 forced-host devices on the CPU) also checks
+per-seed *bitwise* parity of ``run_fleet`` against ``run_batch``,
+records the max deviation (0.0 by construction), and runs
 the headline comparison as an interleaved A/B -- alternating run_batch /
 run_fleet calls, median over ``ab_reps`` -- because the DRAM-bound flat
 vmap's wall time swings with host memory-bandwidth noise while the
@@ -106,13 +111,10 @@ def _time_call(fn, reps: int, warm: bool = True) -> float:
     return best
 
 
-def _worker(devices: int, tiny: bool, out_path: str) -> None:
-    # Append to (not clobber) any operator-set XLA_FLAGS, replacing only a
-    # pre-existing forced device count with this worker's.
-    kept = [f for f in os.environ.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")]
-    os.environ["XLA_FLAGS"] = " ".join(
-        kept + [f"--xla_force_host_platform_device_count={devices}"])
+def _measure(devices: int, tiny: bool, reference: bool) -> dict:
+    """Rows for one device count, in this process, over the first
+    ``devices`` devices; ``reference`` adds the fleet-size sweep, the
+    bitwise parity check and the interleaved A/B."""
     import resource
 
     import numpy as np
@@ -158,7 +160,7 @@ def _worker(devices: int, tiny: bool, out_path: str) -> None:
             })
     rows.append(fleet_row(plan["scaling_fleet"]))
     parity = ab = None
-    if devices == REFERENCE_DEVICES:
+    if reference:
         rows.extend(fleet_row(f) for f in plan["fleet_fleets"]
                     if f != plan["scaling_fleet"])
         # Bitwise parity at the acceptance point: every per-seed output of
@@ -215,15 +217,41 @@ def _worker(devices: int, tiny: bool, out_path: str) -> None:
         "peak_rss_mb": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
         / 1024.0,
     }
+    return result
+
+
+def _worker(devices: int, tiny: bool, out_path: str) -> None:
+    # Append to (not clobber) any operator-set XLA_FLAGS, replacing only a
+    # pre-existing forced device count with this worker's.
+    kept = [f for f in os.environ.get("XLA_FLAGS", "").split()
+            if not f.startswith("--xla_force_host_platform_device_count")]
+    os.environ["XLA_FLAGS"] = " ".join(
+        kept + [f"--xla_force_host_platform_device_count={devices}"])
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    result = _measure(devices, tiny, devices == REFERENCE_DEVICES)
     with open(out_path, "w") as fp:
         json.dump(result, fp)
 
 
 # ---------------------------------------------------------------------------
-# Orchestrator: one subprocess per device count, merged artifact.
+# Orchestrator: forced-host workers on the CPU, in-process on accelerators.
 # ---------------------------------------------------------------------------
 
-def _spawn_worker(devices: int, tiny: bool, out_path: str) -> None:
+def _accelerator_devices() -> int:
+    """Devices of the accelerator this process would run on, 0 on the CPU.
+    With ``JAX_PLATFORMS=cpu`` and JAX not yet imported it answers without
+    importing JAX, so the CPU parent stays off it."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if "jax" not in sys.modules and platforms.split(",")[0] == "cpu":
+        return 0
+    import jax
+
+    return 0 if jax.default_backend() == "cpu" else jax.device_count()
+
+
+def _spawn_worker(devices: int, tiny: bool, out_path: str) -> dict:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in ("src", env.get("PYTHONPATH", "")) if p)
@@ -237,28 +265,43 @@ def _spawn_worker(devices: int, tiny: bool, out_path: str) -> None:
         raise RuntimeError(
             f"bench_fleet worker (devices={devices}) failed:\n"
             f"{proc.stderr[-4000:]}")
+    with open(out_path) as fp:
+        return json.load(fp)
+
+
+def _results(tiny: bool) -> tuple[list[dict], str]:
+    """One result per device count, and the backend that measured them."""
+    counts = _plan(tiny)["device_counts"]
+    n_acc = _accelerator_devices()
+    if n_acc:
+        from repro.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+        counts = [n for n in counts if n <= n_acc] or [1]
+        import jax
+
+        return ([_measure(n, tiny, n == counts[-1]) for n in counts],
+                jax.default_backend())
+    with tempfile.TemporaryDirectory() as tmp:
+        return ([_spawn_worker(n, tiny, os.path.join(tmp, f"worker_{n}.json"))
+                 for n in counts], "cpu")
 
 
 def run(tiny: bool = False) -> dict:
     from benchmarks import common
 
-    plan = _plan(tiny)
     rows, peak_rss, parity, ab = [], {}, None, None
-    with tempfile.TemporaryDirectory() as tmp:
-        for devices in plan["device_counts"]:
-            out_path = os.path.join(tmp, f"worker_{devices}.json")
-            _spawn_worker(devices, tiny, out_path)
-            with open(out_path) as fp:
-                result = json.load(fp)
-            rows.extend(result["rows"])
-            peak_rss[str(devices)] = result["peak_rss_mb"]
-            parity = result["parity"] or parity
-            ab = result["ab"] or ab
+    results, backend = _results(tiny)
+    for result in results:
+        rows.extend(result["rows"])
+        peak_rss[str(result["devices"])] = result["peak_rss_mb"]
+        parity = result["parity"] or parity
+        ab = result["ab"] or ab
 
     return {
         "schema": SCHEMA,
         "tiny": tiny,
-        **common.provenance(),
+        **common.provenance(backend),
         "config": _sim_config(tiny),
         "rows": rows,
         "speedup_8dev_vs_run_batch": ab,

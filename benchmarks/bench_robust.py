@@ -38,6 +38,8 @@ import hashlib
 import json
 import os
 
+from repro.compile_cache import enable_compile_cache
+
 SCHEMA = "bench_robust/v1"
 DEFAULT_OUT = "BENCH_robust.json"
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -324,6 +326,7 @@ def main() -> None:
     ap.add_argument("--out", default=os.path.join(_REPO_ROOT, DEFAULT_OUT),
                     help=f"output path (default: {DEFAULT_OUT} at repo root)")
     args = ap.parse_args()
+    enable_compile_cache()
     data = run(tiny=args.tiny)
     validate(data)
     with open(args.out, "w") as fp:

@@ -9,10 +9,8 @@ import os
 import subprocess
 import time
 
-import jax
 
-
-def provenance() -> dict:
+def provenance(backend: str | None = None) -> dict:
     """Stamp for BENCH_*.json artifacts: the commit and date the numbers were
     measured at plus the jax backend that produced them, so the bench
     trajectory is machine-reconstructable from the artifacts alone.
@@ -22,6 +20,9 @@ def provenance() -> dict:
     with the parent commit plus ``dirty: true`` (the measuring tree IS the
     commit under review); ``dirty: false`` means the stamped commit alone
     reproduces the numbers.
+
+    ``backend`` names the JAX backend that measured the numbers; left None it
+    is this process's own (which imports JAX).
     """
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -34,12 +35,16 @@ def provenance() -> dict:
         dirty = bool(_git("status", "--porcelain").strip())
     except (OSError, subprocess.SubprocessError):
         commit, dirty = "unknown", False
+    if backend is None:
+        import jax
+
+        backend = jax.default_backend()
     return {
         "commit": commit,
         "dirty": dirty,
         "date": datetime.datetime.now(datetime.timezone.utc)
                         .strftime("%Y-%m-%dT%H:%M:%SZ"),
-        "backend": jax.default_backend(),
+        "backend": backend,
     }
 
 
@@ -56,6 +61,8 @@ def validate_provenance(data: dict) -> None:
 
 def time_fn(fn, *args, warmup: int = 2, iters: int = 10) -> float:
     """Median-of-iters wall time in microseconds (blocks on outputs)."""
+    import jax
+
     for _ in range(warmup):
         jax.block_until_ready(fn(*args))
     times = []

@@ -15,6 +15,8 @@ import argparse
 import sys
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -25,6 +27,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="paper-sized long-term sims (slow)")
     args = ap.parse_args()
+    enable_compile_cache()
     wanted = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

@@ -1,54 +1,20 @@
-"""Version-compatibility shims for JAX API movement.
+"""The mesh and ``shard_map`` choices the sharded solvers share.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the top-level
-``jax`` namespace, ``jax.sharding.AxisType`` and the ``axis_types`` kwarg of
-``jax.make_mesh`` appeared later still.  Import from here so the rest of the
-codebase works on both sides of each move.
+``fl.simulator.run_fleet``, ``core.disba.disba_sharded``,
+``launch.mesh.make_fleet_mesh`` and the MoE dispatch build their meshes and
+``shard_map`` calls here, so each choice (replication checking off, one-axis
+Auto meshes) keeps one home.
 """
 from __future__ import annotations
 
-import enum
+import functools
 
 import jax
+from jax.sharding import AxisType
 
-try:  # JAX >= 0.6: top-level export
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # older JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-try:  # JAX >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # older JAX: every mesh axis is implicitly "auto"
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off.
-
-    JAX 0.4.x's ``check_rep`` pass rejects valid ``lax.scan`` carries whose
-    replication differs between input and output (jax-ml/jax#21931-style);
-    newer JAX renamed the flag to ``check_vma``.  Try each spelling.
-    """
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates older signatures without ``axis_types``."""
-    try:
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices,
-                             axis_types=axis_types)
-    except TypeError:  # pre-axis_types JAX: all axes behave as Auto already
-        return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+# ``shard_map`` with replication checking off: the fleet and sharded-solver
+# scans carry values whose replication differs between input and output.
+shard_map_unchecked = functools.partial(jax.shard_map, check_vma=False)
 
 
 def flat_mesh(n_devices: int | None = None, axis_name: str = "data",
@@ -57,9 +23,8 @@ def flat_mesh(n_devices: int | None = None, axis_name: str = "data",
 
     The single mesh-construction path for every batch/seed-sharded solver
     (``fl.simulator.run_fleet``, ``core.disba.disba_sharded``,
-    ``launch.mesh.make_fleet_mesh``): one place encodes the device selection
-    and the version-tolerant ``make_mesh`` call.  ``n_devices=None`` takes
-    every visible device.
+    ``launch.mesh.make_fleet_mesh``).  ``n_devices=None`` takes every
+    visible device.
     """
     if devices is None:
         devices = jax.devices()
@@ -69,22 +34,9 @@ def flat_mesh(n_devices: int | None = None, axis_name: str = "data",
         raise ValueError(
             f"n_devices={n_devices} outside [1, {len(devices)}] visible "
             f"devices")
-    return make_mesh((n_devices,), (axis_name,),
-                     axis_types=(AxisType.Auto,),
-                     devices=devices[:n_devices])
+    return jax.make_mesh((n_devices,), (axis_name,),
+                         axis_types=(AxisType.Auto,),
+                         devices=devices[:n_devices])
 
 
-def abstract_mesh(axis_shapes, axis_names, *, axis_types=None):
-    """``jax.sharding.AbstractMesh`` across its two historical signatures:
-    new JAX takes (sizes, names, axis_types=tuple); 0.4.x takes a single
-    ((name, size), ...) tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names),
-                                         axis_types=axis_types)
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes)))
-
-
-__all__ = ["shard_map", "shard_map_unchecked", "AxisType", "make_mesh",
-           "flat_mesh", "abstract_mesh"]
+__all__ = ["shard_map_unchecked", "flat_mesh"]

@@ -162,11 +162,15 @@ def freq_from_price(svc: ServiceSet, lam: jax.Array, iters: int = BISECT_ITERS) 
     target = 1.0 / jnp.maximum(lam, _TINY)
 
     # Hoisted loop-invariant masking: alpha_m is exactly 0 at masked slots, so
-    # they contribute 0 to the sum without a per-trip ``where``.
+    # they contribute 0 to the sum without a per-trip ``where``.  t_comp is
+    # zeroed there too, so a masked slot's 1 - t^C f stays 1: a slot slower
+    # than every valid client would otherwise hit the TINY floor, whose square
+    # underflows in f32 and turns its 0 numerator into 0/0 = NaN.
     alpha_m = jnp.where(svc.mask, svc.alpha, 0.0)
+    t_comp_m = jnp.where(svc.mask, svc.t_comp, 0.0)
 
     def h(f):  # decreasing convention: target - LHS(f)
-        one_m = jnp.maximum(1.0 - svc.t_comp * f[:, None], _TINY)
+        one_m = jnp.maximum(1.0 - t_comp_m * f[:, None], _TINY)
         lhs = (1.0 + f) * jnp.sum(alpha_m / one_m**2, axis=-1)
         return target - lhs
 

@@ -411,9 +411,12 @@ def _selfish(*, n_bids: int = 5, alpha_fair: float = 0.5,
              intra_backend: str = "reference", iters: int = BISECT_ITERS, **_):
     """Fairness-adjusted multi-bid auction with truthful uniform bids (§V.E)."""
     _freq = freq_fn(intra_backend, iters)
+    # A kernel backend runs the bids' (N, M) mBDF grid on the mbdf kernel too.
+    bid_backend = _intra_impl(intra_backend)
 
     def fn(svc: ServiceSet, b_total):
-        bid = auction.uniform_truthful_bids(svc, n_bids, alpha_fair, iters=iters)
+        bid = auction.uniform_truthful_bids(svc, n_bids, alpha_fair,
+                                            iters=iters, backend=bid_backend)
         b, _ = auction.allocate(bid, b_total)
         return b, _freq(svc, b)
 

@@ -224,8 +224,9 @@ class ControlPlane:
                 f"or grow capacity")
         # Prefer a never-used slot: reusing a freed one makes the episode
         # inexpressible as a single run_scan (arrival, count) trace.
-        virgin = [s for s in self._free if self._arrivals[s] == NEVER]
-        slot = min(virgin) if virgin else min(self._free)
+        free = np.asarray(self._free)
+        virgin = free[self._arrivals[free] == NEVER]
+        slot = int(virgin.min() if virgin.size else free.min())
         self._free.remove(slot)
         if self._arrivals[slot] != NEVER:
             self._mark_unreplayable("slot reuse")
@@ -343,6 +344,17 @@ class ControlPlane:
         self.metrics["decisions"] += 1
         self.decisions.append(decision)
         return decision
+
+    def compiled_step_text(self) -> str:
+        """HLO text of the compiled period step that ``tick`` runs.  Its
+        shapes are fixed by the config, so any state gives the same program;
+        a kernel backend shows up as ``tpu_custom_call`` on a TPU."""
+        n, k = self.cfg.capacity, self.cfg.k_max
+        return self._step.lower(
+            *self._carry, jnp.int32(self._period),
+            jnp.asarray(self._arrivals), jnp.asarray(self._counts),
+            self._key, jnp.ones((n, k), bool),
+        ).compile().as_text()
 
     def _repair_carry(self) -> int:
         """Replace non-finite float entries anywhere in the serving carry

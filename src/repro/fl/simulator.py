@@ -564,8 +564,8 @@ def sharded_chunked_fn(mesh, axis: str, n_chunks: int, chunk: int, episode):
     """Build the compiled fleet sweep for an arbitrary per-episode function:
     shard_map over the seed axis of an outer ``lax.map`` over chunks of the
     vmapped episode.  ``episode(arrivals, counts, key_data) -> pytree`` takes
-    one seed's inputs (keys as raw uint32 key data -- typed PRNG key arrays
-    predate stable shard_map support on the oldest JAX this repo carries).
+    one seed's inputs (keys as raw uint32 key data, which shard like any
+    other array).
 
     Shared by the duration engine's ``run_fleet`` and the co-training
     engine's ``fl.cotrain.run_cotrain_fleet``; callers lru_cache the result
@@ -664,13 +664,15 @@ def run_fleet(cfg: SimConfig, seeds, net: network.NetworkConfig | None = None,
     arrivals, counts = _draws(keys, **_draw_statics(cfg, net))
     statics = _episode_statics(cfg, net, _k_cap(cfg))
     fn = _fleet_fn(mesh, axis, n_chunks, chunk, tuple(statics.items()))
+    sharded = fn(arrivals, counts, jax.random.key_data(keys))
+    # The devices that actually hold the sweep's output shards.
+    device_ids = sorted(d.id for d in sharded[1].sharding.device_set)
     rounds_done, duration, hist = jax.tree_util.tree_map(
-        lambda x: x[:n_seeds],
-        fn(arrivals, counts, jax.random.key_data(keys)),
-    )
+        lambda x: x[:n_seeds], sharded)
     out = _summarize_batch(cfg, seeds, rounds_done, duration, hist)
     out["fleet"] = {"n_devices": n_dev, "mesh_axis": axis, "chunk": chunk,
-                    "n_chunks": n_chunks, "padded_to": len(padded)}
+                    "n_chunks": n_chunks, "padded_to": len(padded),
+                    "device_ids": device_ids}
     return out
 
 

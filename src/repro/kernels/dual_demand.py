@@ -51,6 +51,10 @@ def demand_slope_tile(alpha, tcomp, lam, iters: int):
     bitwise-identical between the per-evaluation and whole-solve launches.
     """
     valid = alpha > 0.0
+    # Padded slots read t^C = 0, so their 1 - t^C f stays 1: a slot slower
+    # than every valid client would otherwise hit the TINY floor, whose
+    # square underflows in f32 and turns its 0 numerator into 0/0 = NaN.
+    tcomp = jnp.where(valid, tcomp, 0.0)
 
     asum = jnp.sum(alpha, axis=1, keepdims=True)                 # (TN, 1)
     tcmax = jnp.max(jnp.where(valid, tcomp, NEG_INF), axis=1, keepdims=True)

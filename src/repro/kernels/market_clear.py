@@ -32,10 +32,9 @@ tests/test_market_clear.py), not bitwise; the bitwise fallback is
 delegates to the reference solver itself.
 
 ``mbdf_demand`` moves the auction's joint (N, M) ``fairness.mbdf_grid``
-bisection onto the same tiling conventions: grid (n_tiles, M), each launch
-step solving one (TILE_N, 1) price column against its (TILE_N, K) service
-tile (the tile is re-used across the M consecutive grid steps, so services
-stream from HBM once, not M times).
+bisection onto the same tiling conventions: grid (n_tiles,), each launch
+step solving all M price columns of a (TILE_N, M) block against its
+(TILE_N, K) service tile, so services stream from HBM once, not M times.
 
 Padding conventions match ``bisect_alloc``/``dual_demand``: padded client
 slots carry alpha = 0, K pads to the 128-lane multiple, N to the tile.
@@ -49,6 +48,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.dual_demand import (
     F_CEIL, NEG_INF, TINY, demand_slope_tile,
@@ -183,8 +183,14 @@ def market_clear(
     kernel = functools.partial(
         _market_clear_kernel, iters=iters, inner_iters=inner_iters,
         newton_inner_iters=newton_inner_iters, tile_n=tile_n, n_tiles=n_tiles)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    # Mosaic cannot store scalars to VMEM: B, the warm seed and lam travel
+    # through (1, 1) SMEM refs.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     b, f, lam = pl.pallas_call(
         kernel,
+        in_specs=[vmem, vmem, smem, smem],
+        out_specs=[vmem, vmem, smem],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
@@ -204,33 +210,43 @@ def market_clear(
 def _mbdf_kernel(alpha_ref, tcomp_ref, price_ref, b_ref, *,
                  alpha_fair: float, iters: int):
     alpha = alpha_ref[...]                       # (TN, K)
-    tcomp = tcomp_ref[...]                       # (TN, K)
-    price = price_ref[...]                       # (TN, 1)
     valid = alpha > 0.0
+    # Padded slots read t^C = 0 (see ``demand_slope_tile``).
+    tcomp = jnp.where(valid, tcomp_ref[...], 0.0)
+    prices = price_ref[...]                      # (TN, M)
+    m = prices.shape[1]
+    cols = [prices[:, j:j + 1] for j in range(m)]
 
     asum = jnp.sum(alpha, axis=1, keepdims=True)
     tcmax = jnp.max(jnp.where(valid, tcomp, NEG_INF), axis=1, keepdims=True)
     active = asum > 0.0
     f_hi = jnp.where(active, F_CEIL / jnp.maximum(tcmax, TINY), 0.0)
 
-    def body(_, carry):
-        lo, hi = carry
-        f = 0.5 * (lo + hi)
+    def q_at(f):
         one_m = jnp.maximum(1.0 - tcomp * f, TINY)
         s = jnp.sum(alpha / (one_m * one_m), axis=1, keepdims=True)
         # q(f) = g'(b) at f: [(1-a) + a/(1+f)] * f*'(b)  (Eq. 21 derivative)
-        q = ((1.0 - alpha_fair) + alpha_fair / (1.0 + f)) \
+        return ((1.0 - alpha_fair) + alpha_fair / (1.0 + f)) \
             * (1.0 / jnp.maximum(s, TINY))
-        go_right = (q - price) > 0.0             # q decreasing in f
-        return jnp.where(go_right, f, lo), jnp.where(go_right, hi, f)
 
-    lo, hi = jax.lax.fori_loop(0, iters, body, (jnp.zeros_like(f_hi), f_hi))
-    f = 0.5 * (lo + hi)
+    def body(_, carry):
+        # All M price columns bisect together: one (lo, hi) pair per column.
+        out = []
+        for price, (lo, hi) in zip(cols, carry):
+            f = 0.5 * (lo + hi)
+            go_right = (q_at(f) - price) > 0.0   # q decreasing in f
+            out.append((jnp.where(go_right, f, lo), jnp.where(go_right, hi, f)))
+        return tuple(out)
+
+    lo0 = jnp.zeros_like(f_hi)
+    brackets = jax.lax.fori_loop(0, iters, body,
+                                 tuple((lo0, f_hi) for _ in range(m)))
 
     p_max = jnp.where(active, 1.0 / jnp.maximum(asum, TINY), 0.0)
-    f = jnp.where(price >= p_max, 0.0, f)
-    one_m = jnp.maximum(1.0 - tcomp * f, TINY)
-    b_ref[...] = jnp.sum(alpha * f / one_m, axis=1, keepdims=True)
+    for j, (price, (lo, hi)) in enumerate(zip(cols, brackets)):
+        f = jnp.where(price >= p_max, 0.0, 0.5 * (lo + hi))
+        one_m = jnp.maximum(1.0 - tcomp * f, TINY)
+        b_ref[:, j:j + 1] = jnp.sum(alpha * f / one_m, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("alpha_fair", "iters", "tile_n",
@@ -247,9 +263,11 @@ def mbdf_demand(
 ) -> jax.Array:
     """Modified bandwidth demand d_n(p_m) at the whole (N, M) grid -> (N, M).
 
-    Grid (n_tiles, M): the service tile's index map is constant across the M
-    consecutive price columns, so each (TILE_N, K) tile streams from HBM once
-    for all M joint bisections.
+    Grid (n_tiles,): each step takes one (TILE_N, K) service tile with its
+    whole (TILE_N, M) price block, so the tile streams from HBM once and all
+    M joint bisections run on it in VMEM.  The price and output blocks span
+    the full M axis, which is what Mosaic requires of a last block dimension
+    that is not a multiple of 128.
     """
     n, k = alpha.shape
     m = prices.shape[1]
@@ -262,13 +280,13 @@ def mbdf_demand(
 
     out = pl.pallas_call(
         functools.partial(_mbdf_kernel, alpha_fair=alpha_fair, iters=iters),
-        grid=(n_pad // tile_n, m),
+        grid=(n_pad // tile_n,),
         in_specs=[
-            pl.BlockSpec((tile_n, k_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_n, k_pad), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_n, 1), lambda i, j: (i, j)),
+            pl.BlockSpec((tile_n, k_pad), lambda i: (i, 0)),
+            pl.BlockSpec((tile_n, k_pad), lambda i: (i, 0)),
+            pl.BlockSpec((tile_n, m), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tile_n, 1), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((tile_n, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, m), jnp.float32),
         interpret=interpret,
     )(alpha.astype(jnp.float32), t_comp.astype(jnp.float32),

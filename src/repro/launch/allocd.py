@@ -45,6 +45,7 @@ from typing import Any
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.core import network
 from repro.fl import control_plane
 from repro.fl.control_plane import ControlPlane, ControlPlaneConfig, Decision
@@ -269,8 +270,11 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None) -> int:
+    """Serve the synthetic workload; returns the process exit code, which is
+    non-zero when ``--check`` finds a mismatch or an unreplayable trace."""
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     cfg = ControlPlaneConfig(
         capacity=args.capacity, k_max=args.k_max, policy=args.policy,
         warm_start=not args.cold, rounds_required=args.rounds,
@@ -295,28 +299,30 @@ def main(argv: list[str] | None = None) -> None:
           f"stale_decisions={m['stale_decisions']} "
           f"heartbeat_drops={m['heartbeat_drops']}")
     # Degradation counters -- all zero on a healthy run, and none of them is
-    # ever silent (ISSUE 8): solver fallbacks, equal-share degradations,
-    # non-finite catches, carry repairs, skipped checkpoints, admit retries.
+    # ever silent: solver fallbacks, equal-share degradations, non-finite
+    # catches, carry repairs, skipped checkpoints, admit retries.
     print(f"[allocd] solver_fallbacks={m['solver_fallbacks']} "
           f"degraded_decisions={m['degraded_decisions']} "
           f"nonfinite_decisions={m['nonfinite_decisions']} "
           f"carry_repairs={m['carry_repairs']} "
           f"checkpoint_skips={m['checkpoint_skips']} "
           f"admit_retries={m['admit_retries']}")
-    if args.check:
-        if not daemon.plane.replayable:
-            reasons = (daemon.plane.unreplayable_reasons
-                       or ["slot reuse / forced retire"])
-            print(f"[allocd] trace not replayable ({reasons})")
-            return
-        ref = daemon.plane.replay_reference()
-        b_ref = np.asarray(ref["history"]["b"])
-        b_live = np.stack([d.b for d in daemon.plane.decisions])
-        n = min(len(b_live), len(b_ref))
-        exact = bool(np.array_equal(b_live[:n], b_ref[:n]))
-        print(f"[allocd] replay check over {n} periods: "
-              f"{'bitwise equal' if exact else 'MISMATCH'}")
+    if not args.check:
+        return 0
+    if not daemon.plane.replayable:
+        reasons = (daemon.plane.unreplayable_reasons
+                   or ["slot reuse / forced retire"])
+        print(f"[allocd] trace not replayable ({reasons})")
+        return 1
+    ref = daemon.plane.replay_reference()
+    b_ref = np.asarray(ref["history"]["b"])
+    b_live = np.stack([d.b for d in daemon.plane.decisions])
+    n = min(len(b_live), len(b_ref))
+    exact = bool(np.array_equal(b_live[:n], b_ref[:n]))
+    print(f"[allocd] replay check over {n} periods: "
+          f"{'bitwise equal' if exact else 'MISMATCH'}")
+    return 0 if exact else 1
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -7,7 +7,10 @@ smoke tests must keep seeing 1 device.
 """
 from __future__ import annotations
 
-from repro.compat import AxisType, flat_mesh, make_mesh
+import jax
+from jax.sharding import AxisType
+
+from repro.compat import flat_mesh
 
 FLEET_AXIS = "seeds"
 
@@ -31,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     gradient all-reduce (DESIGN.md §4)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
@@ -40,5 +43,5 @@ def make_elastic_mesh(n_devices: int, model_parallel: int = 16):
     while model_parallel > 1 and n_devices % model_parallel != 0:
         model_parallel //= 2
     data = n_devices // model_parallel
-    return make_mesh((data, model_parallel), ("data", "model"),
-                     axis_types=(AxisType.Auto, AxisType.Auto))
+    return jax.make_mesh((data, model_parallel), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))

@@ -106,3 +106,22 @@ def test_per_period_policy_ordering():
                    baselines.proportional):
             _, f = fn(svc, B)
             assert coop >= float(jnp.sum(jnp.log1p(f))) - 1e-5
+
+
+def test_bench_fleet_measures_in_process_on_an_accelerator(monkeypatch,
+                                                           tmp_path):
+    """Where the parent holds accelerator devices a child could not reach
+    them, so every device count is measured in-process: no worker spawns."""
+    from benchmarks import bench_fleet
+    from repro.compile_cache import CACHE_ENV
+
+    def no_spawn(*args, **kwargs):
+        raise AssertionError("spawned a worker process")
+
+    # Set before the call, the variable makes the cache helper a no-op.
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    monkeypatch.setattr(bench_fleet, "_accelerator_devices", lambda: 1)
+    monkeypatch.setattr(bench_fleet, "_spawn_worker", no_spawn)
+    data = bench_fleet.run(tiny=True)
+    bench_fleet.validate(data)
+    assert {row["devices"] for row in data["rows"]} == {1}

@@ -9,6 +9,8 @@ liveness, COMMIT-protocol checkpoint/resume, and the deadline-miss
 (stale-decision) degradation of the asyncio front end.
 """
 import asyncio
+import importlib.util
+import pathlib
 
 import jax
 import numpy as np
@@ -16,6 +18,7 @@ import pytest
 
 from repro import scenarios
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import CACHE_ENV, enable_compile_cache
 from repro.distributed import fault
 from repro.fl import control_plane
 from repro.fl.control_plane import ControlPlane, ControlPlaneConfig
@@ -302,3 +305,88 @@ def test_collect_alloc_requires_history():
                               collect_alloc=True)
     with pytest.raises(ValueError, match="collect_alloc"):
         simulator.run_scan(cfg)
+
+
+# ---------------------------------------------------------------------------
+# Entry points: allocd --check, the compile cache, chip_smoke.py.
+# ---------------------------------------------------------------------------
+
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _skew_replay(monkeypatch):
+    real = ControlPlane.replay_reference
+
+    def skewed(self):
+        out = real(self)
+        out["history"]["b"] = np.asarray(out["history"]["b"]) + 1.0
+        return out
+
+    monkeypatch.setattr(ControlPlane, "replay_reference", skewed)
+
+
+def _break_replayability(monkeypatch):
+    real = ControlPlane.tick
+
+    def tick(self):
+        self._mark_unreplayable("injected fault")
+        return real(self)
+
+    monkeypatch.setattr(ControlPlane, "tick", tick)
+
+
+@pytest.mark.parametrize("fault,rc", [
+    (None, 0),
+    (_skew_replay, 1),
+    (_break_replayability, 1),
+])
+def test_allocd_check_exit_code(monkeypatch, tmp_path, fault, rc):
+    """``allocd --check`` exits non-zero on a replay mismatch and on a
+    trace it cannot replay, never just printing the verdict."""
+    # Set before the call, the variable makes the cache helper a no-op.
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    if fault is not None:
+        fault(monkeypatch)
+    argv = ["--capacity", "8", "--k-max", "6", "--periods", "6",
+            "--rate", "1.0", "--rounds", "100000", "--check"]
+    assert allocd.main(argv) == rc
+
+
+def test_compile_cache_follows_env(monkeypatch, tmp_path):
+    monkeypatch.setenv(CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch):
+    monkeypatch.delenv(CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = enable_compile_cache()
+        assert path == str(_REPO_ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def _chip_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", _REPO_ROOT / "chip_smoke.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.mark.parametrize("argv", [[], ["--four-chips"]])
+def test_chip_smoke_refuses_a_host_without_tpu(capsys, argv):
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("a TPU is attached")
+    assert _chip_smoke().main(argv) != 0
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_chip_smoke_paper_cell_replays_at_toy_size():
+    """The smoke's served paper-cell phase (admissions, replay, counters) at
+    a size the CPU runs in seconds."""
+    _chip_smoke().paper_cell(capacity=8, k_max=48, n_serve=4)

@@ -13,7 +13,7 @@ import pytest
 
 from repro import configs
 from repro.checkpoint import CheckpointManager
-from repro.compat import AxisType, abstract_mesh
+from jax.sharding import AbstractMesh, AxisType
 from repro.distributed import elastic, fault, sharding
 from repro.models import registry
 
@@ -21,8 +21,8 @@ from repro.models import registry
 def _mesh_1d():
     """Production-shaped 16x16 mesh, abstract (no devices needed): sharding
     rules only read axis names/sizes."""
-    return abstract_mesh((16, 16), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    return AbstractMesh((16, 16), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
 
 
 def test_param_sharding_rules_shapes():
@@ -73,12 +73,12 @@ MULTIDEV = textwrap.dedent(
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed import collectives
 
-    from repro.compat import make_mesh, AxisType
-    mesh = make_mesh((2, 4), ("pod", "data"),
-                     axis_types=(AxisType.Auto,) * 2)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("pod", "data"),
+                         axis_types=(AxisType.Auto,) * 2)
     # local shard (4, 16): dim0 must divide the intra-pod (data=4) axis for
     # the reduce-scatter leg
     x = jnp.arange(32 * 16, dtype=jnp.float32).reshape(32, 16)
@@ -195,9 +195,9 @@ EP_MOE_SCRIPT = textwrap.dedent(
     p = moe.init_moe(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (8, 16, cfg.d_model))
     ref = moe.apply_moe_dense_ref(p, x, cfg)
-    from repro.compat import make_mesh, AxisType
-    mesh = make_mesh((2, 4), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     dist_api.set_mesh(mesh)
     out, aux = jax.jit(lambda p_, x_: moe.apply_moe(p_, x_, cfg))(p, x)
     g = jax.jit(jax.grad(

@@ -82,7 +82,8 @@ def test_fleet_bitwise_equals_batch_and_scan_uneven_chunked():
     seeds = [0, 1, 2, 3, 4]
     fleet = simulator.run_fleet(cfg, seeds, mesh=_mesh1(), chunk_size=2)
     assert fleet["fleet"] == {"n_devices": 1, "mesh_axis": "seeds",
-                              "chunk": 2, "n_chunks": 3, "padded_to": 6}
+                              "chunk": 2, "n_chunks": 3, "padded_to": 6,
+                              "device_ids": [jax.devices()[0].id]}
     batch = simulator.run_batch(cfg, seeds)
     np.testing.assert_array_equal(fleet["durations"], batch["durations"])
     np.testing.assert_array_equal(fleet["finished"], batch["finished"])
@@ -237,6 +238,7 @@ MULTIDEV_FLEET_SCRIPT = textwrap.dedent(
     fleet = simulator.run_fleet(cfg, seeds, chunk_size=2)
     assert simulator.trace_count() == 1, simulator.trace_count()
     assert fleet["fleet"]["n_devices"] == 8, fleet["fleet"]
+    assert fleet["fleet"]["device_ids"] == [d.id for d in jax.devices()]
     batch = simulator.run_batch(cfg, seeds)
     np.testing.assert_array_equal(fleet["durations"], batch["durations"])
     for key in ("freq_sum", "objective", "n_active", "n_clients"):

@@ -7,7 +7,7 @@ import math
 import pytest
 
 from repro import configs
-from repro.compat import AxisType, abstract_mesh
+from jax.sharding import AbstractMesh, AxisType
 from repro.distributed import sharding
 from repro.models import registry
 
@@ -15,8 +15,8 @@ from repro.models import registry
 def _meshes():
     at = (AxisType.Auto,)
     return [
-        abstract_mesh((16, 16), ("data", "model"), axis_types=at * 2),
-        abstract_mesh((2, 16, 16), ("pod", "data", "model"), axis_types=at * 3),
+        AbstractMesh((16, 16), ("data", "model"), axis_types=at * 2),
+        AbstractMesh((2, 16, 16), ("pod", "data", "model"), axis_types=at * 3),
     ]
 
 

@@ -1,7 +1,8 @@
 """Tile-edge padding parity: every allocation kernel must be exact on shapes
 where N is NOT a multiple of its row tile and K is NOT a multiple of the
 128-lane pad, with ragged masks and fully-inactive service slots riding in
-the padded region.  Also the unified ``ops._resolve_backend`` dispatch rule,
+the padded region, and masked slots carrying a t^C above every valid
+client's.  Also the unified ``ops._resolve_backend`` dispatch rule,
 including the ``REPRO_FORCE_PALLAS`` CI override.
 """
 import jax
@@ -32,7 +33,9 @@ def _edge_set(seed, n, k):
         mask[i, : rng.integers(1, k + 1)] = True
     mask[rng.integers(0, n)] = False          # a fully-inactive slot
     alpha = np.where(mask, alpha, 0.0)
-    t_comp = np.where(mask, t_comp, 0.0)
+    # Masked slots slower than every valid client (a dropped straggler):
+    # their 1 - t^C f must not reach the TINY floor and 0/0.
+    t_comp = np.where(mask, t_comp, 99.0).astype(np.float32)
     return ServiceSet(alpha=jnp.asarray(alpha), t_comp=jnp.asarray(t_comp),
                       mask=jnp.asarray(mask))
 

@@ -169,6 +169,28 @@ def round_time_fn(
                      f"expected one of {INTRA_BACKENDS}")
 
 
+def launch_shape(name: str, *, intra_backend: str = "reference",
+                 warm_start: bool = False, n: int, k: int
+                 ) -> tuple[int, int] | None:
+    """(rows, lanes) on which policy ``name``'s per-period solves run for an
+    (n, k) service set: the set itself where no kernel runs, else the
+    padding of the Pallas wrappers the policy calls, each with its own row
+    tile.  None for cold ``coop`` on ``pallas``, whose dual bisection runs
+    on the reference at (n, k) and whose f*(b) on ``bisect_alloc``'s
+    padding, so its solves share no one shape."""
+    from repro.kernels import bisect_alloc, market_clear, tiling
+
+    if intra_backend == "reference" or name == "ec":
+        return n, k
+    if name == "coop" and intra_backend == "megakernel":
+        return tiling.padded_shape(n, k, market_clear.TILE_N)
+    if name == "coop" and not warm_start:
+        return None
+    # bisect_alloc's tile, which dual_demand and mbdf_demand share
+    # (tests/test_obs.py checks each combination against the traced step).
+    return tiling.padded_shape(n, k, bisect_alloc.TILE_N)
+
+
 # ---------------------------------------------------------------------------
 # Registry.
 # ---------------------------------------------------------------------------

@@ -82,7 +82,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, scenarios
+from repro import compat, obs, scenarios
 from repro.core import network, policy as policy_mod
 from repro.core.types import (ServiceSet, mask_clients, mask_inactive,
                               scale_uplink)
@@ -95,18 +95,18 @@ POLICIES = ("coop", "selfish", "ec", "es", "pp")
 # bisection/Newton trips, large enough to amortize the chunk loop.
 FLEET_CHUNK = 64
 
-# Incremented each time the per-period allocation step is *traced* (not run).
-# The scan engine's acceptance bar is exactly one trace per episode shape --
-# mask flips must never retrigger compilation.
-_TRACE_COUNTS = {"allocation_step": 0}
+# Counter incremented each time the per-period allocation step is *traced*
+# (not run).  The scan engine's acceptance bar is exactly one trace per
+# episode shape -- mask flips must never retrigger compilation.
+TRACE_COUNTER = "repro.period_step.traces"
 
 
 def trace_count() -> int:
-    return _TRACE_COUNTS["allocation_step"]
+    return obs.counter(TRACE_COUNTER)
 
 
 def reset_trace_count() -> None:
-    _TRACE_COUNTS["allocation_step"] = 0
+    obs.reset(TRACE_COUNTER)
 
 
 @dataclasses.dataclass
@@ -277,7 +277,7 @@ def _period_step(rounds_done, duration, chan_state, churn_state, pol_state,
     traced graph untouched, which is what keeps every duration engine and
     the committed goldens bitwise-pinned.
     """
-    _TRACE_COUNTS["allocation_step"] += 1
+    obs.count(TRACE_COUNTER)
     key_p = jax.random.fold_in(key, period)
     if chan_rebuilds:
         # The channel process reconstructs the ServiceSet itself (on this
@@ -651,29 +651,101 @@ def run_fleet(cfg: SimConfig, seeds, net: network.NetworkConfig | None = None,
     to ``run_batch``/``run_scan`` under every mesh size, chunk size, and
     fleet-size remainder, and the per-period allocation step traces exactly
     once.  Returns the ``run_batch`` summary dict plus a ``"fleet"`` record
-    of the sweep geometry.
+    of the sweep geometry, whose ``"work"`` entry (``fleet_work``) is also
+    appended to ``obs``'s call log.
+
+    Each call is the span ``repro.fleet.call`` with three children:
+    ``repro.fleet.prepare`` (draws, statics, dispatch), ``repro.fleet.device``
+    (waiting for the sharded outputs) and ``repro.fleet.collect`` (slice,
+    transfer, summary, work counts).
     """
     net = net or _default_net(cfg)
     seeds = [int(s) for s in seeds]
-    mesh, axis, n_dev, chunk, n_chunks, padded = fleet_geometry(
-        seeds, mesh, chunk_size)
     n_seeds = len(seeds)
-    # Padded with repeats of the last seed: identical shapes on every device;
-    # the pad episodes' outputs are sliced off (on device) before transfer.
-    keys = _episode_keys(padded)
-    arrivals, counts = _draws(keys, **_draw_statics(cfg, net))
-    statics = _episode_statics(cfg, net, _k_cap(cfg))
-    fn = _fleet_fn(mesh, axis, n_chunks, chunk, tuple(statics.items()))
-    sharded = fn(arrivals, counts, jax.random.key_data(keys))
-    # The devices that actually hold the sweep's output shards.
-    device_ids = sorted(d.id for d in sharded[1].sharding.device_set)
-    rounds_done, duration, hist = jax.tree_util.tree_map(
-        lambda x: x[:n_seeds], sharded)
-    out = _summarize_batch(cfg, seeds, rounds_done, duration, hist)
+    with obs.span("repro.fleet.call") as call:
+        with obs.span("repro.fleet.prepare") as prepare:
+            mesh, axis, n_dev, chunk, n_chunks, padded = fleet_geometry(
+                seeds, mesh, chunk_size)
+            # Padded with repeats of the last seed: identical shapes on every
+            # device; the pad episodes' outputs are sliced off (on device)
+            # before transfer.
+            keys = _episode_keys(padded)
+            arrivals, counts = _draws(keys, **_draw_statics(cfg, net))
+            statics = _episode_statics(cfg, net, _k_cap(cfg))
+            fn = _fleet_fn(mesh, axis, n_chunks, chunk, tuple(statics.items()))
+            sharded = fn(arrivals, counts, jax.random.key_data(keys))
+        with obs.span("repro.fleet.device") as device:
+            sharded = jax.block_until_ready(sharded)
+        with obs.span("repro.fleet.collect") as collect:
+            # The devices that actually hold the sweep's output shards.
+            device_ids = sorted(d.id for d in sharded[1].sharding.device_set)
+            rounds_done, duration, hist = jax.tree_util.tree_map(
+                lambda x: x[:n_seeds], sharded)
+            out = _summarize_batch(cfg, seeds, rounds_done, duration, hist)
+            work = fleet_work(cfg, out, n_dev=n_dev, chunk=chunk,
+                              n_chunks=n_chunks, padded_to=len(padded))
+    work.update(start=call.start, call_s=call.seconds,
+                prepare_s=prepare.seconds, device_s=device.seconds,
+                collect_s=collect.seconds)
+    obs.record_call(work)
     out["fleet"] = {"n_devices": n_dev, "mesh_axis": axis, "chunk": chunk,
                     "n_chunks": n_chunks, "padded_to": len(padded),
-                    "device_ids": device_ids}
+                    "device_ids": device_ids, "work": work}
     return out
+
+
+def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
+               n_chunks: int, padded_to: int) -> dict:
+    """What one fleet sweep launched and how much of it was live, from the
+    per-episode aggregates the sweep returns (or its history).
+
+    Counted over the padded fleet, as the devices run it: pad episodes
+    repeat the last seed, and every episode is bitwise its own ``run_scan``,
+    so a pad episode's counts are the last episode's.  An episode is live
+    up to and including the period in which its last service finishes
+    (``periods``); a chunk is live while any of its episodes is.  Live rows
+    are active services and live lanes their enrolled clients, summed over
+    episode-periods.  ``rows`` x ``lanes`` is the shape the policy's solves
+    run on per episode and period (``policy.launch_shape``; None where they
+    share none).  Each waste is counted within the one before it, so the
+    three shares multiply to live lanes over every lane launched:
+    ``chunk_live_periods / step_launches`` (periods),
+    ``live_rows / rows_in_live_chunks`` (rows while the chunk is live) and
+    ``live_lanes / lanes_of_live_rows`` (lanes of active services).
+    """
+    if cfg.collect_history:
+        done = out["history"]["all_done"]
+        periods = np.where(done.any(axis=1), done.argmax(axis=1) + 1,
+                           cfg.max_periods)
+        n_active = out["history"]["n_active"].sum(axis=1)
+        n_clients = out["history"]["n_clients"].sum(axis=1)
+    else:
+        periods = out["periods"]
+        n_active, n_clients = (out["totals"][k]
+                               for k in ("n_active", "n_clients"))
+    pad = padded_to - len(periods)
+    periods, n_active, n_clients = (
+        np.pad(np.asarray(x, np.int64), (0, pad), mode="edge")
+        for x in (periods, n_active, n_clients))
+    rows, lanes = policy_mod.launch_shape(
+        cfg.policy, intra_backend=cfg.intra_backend,
+        warm_start=cfg.warm_start, n=cfg.n_services_total,
+        k=_k_cap(cfg)) or (None, None)
+    chunk_live = int(periods.reshape(-1, chunk).max(axis=1).sum())
+    live_rows = int(n_active.sum())
+    return {
+        "episodes": padded_to - pad, "padded_episodes": padded_to,
+        "chunk": chunk, "n_chunks": n_chunks, "max_periods": cfg.max_periods,
+        "step_launches": n_dev * n_chunks * cfg.max_periods,
+        "scanned_periods": padded_to * cfg.max_periods,
+        "live_periods": int(periods.sum()),
+        "chunk_live_periods": chunk_live,
+        "rows": rows, "lanes": lanes,
+        "live_rows": live_rows, "live_lanes": int(n_clients.sum()),
+        "rows_in_live_chunks": None if rows is None
+        else chunk_live * chunk * rows,
+        "lanes_of_live_rows": None if lanes is None else live_rows * lanes,
+    }
 
 
 # ---------------------------------------------------------------------------

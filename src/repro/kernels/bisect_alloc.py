@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TILE_N = 8
+from repro.kernels.tiling import TILE_N, padded_shape
+
 NEG_INF = -1e30
 TINY = 1e-30
 
@@ -73,9 +74,7 @@ def bisect_alloc(
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (t_star (N,), b_alloc (N, K))."""
     n, k = alpha.shape
-    # pad N to the tile and K to the lane width
-    k_pad = (k + 127) // 128 * 128
-    n_pad = (n + tile_n - 1) // tile_n * tile_n
+    n_pad, k_pad = padded_shape(n, k, tile_n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))

@@ -53,9 +53,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.dual_demand import (
     F_CEIL, NEG_INF, TINY, demand_slope_tile,
 )
+from repro.kernels.tiling import TILE_N as TILE_N_MBDF  # (N, M) mbdf grid
+from repro.kernels.tiling import padded_shape
 
 TILE_N = 128      # row tile of the megakernel's internal loop
-TILE_N_MBDF = 8   # row tile of the (N, M) mbdf grid kernel
 
 
 def _freq_tile(alpha, tcomp, b, iters: int):
@@ -173,8 +174,7 @@ def market_clear(
     """One fused launch of the whole market clear.  Returns (b (N,), f (N,),
     lam ())."""
     n, k = alpha.shape
-    k_pad = (k + 127) // 128 * 128
-    n_pad = (n + tile_n - 1) // tile_n * tile_n
+    n_pad, k_pad = padded_shape(n, k, tile_n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))
@@ -271,8 +271,7 @@ def mbdf_demand(
     """
     n, k = alpha.shape
     m = prices.shape[1]
-    k_pad = (k + 127) // 128 * 128
-    n_pad = (n + tile_n - 1) // tile_n * tile_n
+    n_pad, k_pad = padded_shape(n, k, tile_n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))

@@ -81,9 +81,11 @@ def test_fleet_bitwise_equals_batch_and_scan_uneven_chunked():
     cfg = _cfg()
     seeds = [0, 1, 2, 3, 4]
     fleet = simulator.run_fleet(cfg, seeds, mesh=_mesh1(), chunk_size=2)
+    work = fleet["fleet"].pop("work")
     assert fleet["fleet"] == {"n_devices": 1, "mesh_axis": "seeds",
                               "chunk": 2, "n_chunks": 3, "padded_to": 6,
                               "device_ids": [jax.devices()[0].id]}
+    assert (work["episodes"], work["padded_episodes"]) == (5, 6)
     batch = simulator.run_batch(cfg, seeds)
     np.testing.assert_array_equal(fleet["durations"], batch["durations"])
     np.testing.assert_array_equal(fleet["finished"], batch["finished"])

@@ -117,6 +117,37 @@ def test_bisect_alloc_budget_and_equalization():
     )
 
 
+@pytest.mark.parametrize("kernel", ["bisect_alloc", "mbdf_demand",
+                                    "dual_demand", "market_clear"])
+@pytest.mark.parametrize("n,k", [(10, 48), (8, 128), (3, 130), (129, 7)])
+def test_padded_shape_is_what_each_wrapper_launches(kernel, n, k):
+    """``tiling.padded_shape`` is the (rows, lanes) every wrapper hands
+    ``pallas_call``; ``policy.launch_shape`` reports it for the fleet
+    engine's work counts."""
+    from jaxpr_shapes import pallas_input_shapes
+
+    from repro.kernels import dual_demand, market_clear
+    from repro.kernels.tiling import padded_shape
+
+    a = jnp.ones((n, k), jnp.float32)
+    calls = {
+        "bisect_alloc": (lambda: bisect_alloc(a, a, jnp.ones((n,)),
+                                              interpret=True),
+                         padded_shape(n, k)),
+        "mbdf_demand": (lambda: market_clear.mbdf_demand(
+            a, a, jnp.ones((n, 5)), 0.5, interpret=True),
+            padded_shape(n, k, market_clear.TILE_N_MBDF)),
+        "dual_demand": (lambda: dual_demand.dual_demand(a, a, 1.0,
+                                                        interpret=True),
+                        padded_shape(n, k, dual_demand.TILE_N)),
+        "market_clear": (lambda: market_clear.market_clear(
+            a, a, 10.0, 0.0, interpret=True),
+            padded_shape(n, k, market_clear.TILE_N)),
+    }
+    fn, want = calls[kernel]
+    assert pallas_input_shapes(fn) == [want]
+
+
 # ---------------------------------------------------------------------------
 # mlstm_chunk
 # ---------------------------------------------------------------------------

@@ -86,7 +86,7 @@ def mbdf_grid(
 
     ``backend="pallas"`` dispatches to the ``kernels/market_clear``
     (N, M)-grid kernel on the market tiling conventions instead: each
-    (TILE_N, K) service tile streams from HBM once for all M price columns
+    (tile, K) row block streams from HBM once for all M price columns
     (no N*M row replication is ever materialized).  Exact-to-dtype against
     the reference (tests/test_market_clear.py).
     """

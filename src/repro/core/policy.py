@@ -170,25 +170,45 @@ def round_time_fn(
 
 
 def launch_shape(name: str, *, intra_backend: str = "reference",
-                 warm_start: bool = False, n: int, k: int
+                 warm_start: bool = False, n: int, k: int, batch: int = 1
                  ) -> tuple[int, int] | None:
-    """(rows, lanes) on which policy ``name``'s per-period solves run for an
-    (n, k) service set: the set itself where no kernel runs, else the
-    padding of the Pallas wrappers the policy calls, each with its own row
-    tile.  None for cold ``coop`` on ``pallas``, whose dual bisection runs
-    on the reference at (n, k) and whose f*(b) on ``bisect_alloc``'s
-    padding, so its solves share no one shape."""
-    from repro.kernels import bisect_alloc, market_clear, tiling
+    """(rows, lanes) of one launch of policy ``name``'s per-period solves,
+    over ``batch`` (n, k) service sets vmapped together: the sets
+    themselves where no kernel runs, else the padding of the Pallas
+    wrappers the policy calls.  ``bisect_alloc``, ``dual_demand`` and
+    ``mbdf_demand`` fold the batch into their rows (``tiling.fold_rows``)
+    and pad the folded rows once; the megakernel is not folded, so each set
+    pads to its own tile of 128.  None for cold ``coop`` on ``pallas``,
+    whose dual bisection runs on the reference at (n, k) and whose f*(b)
+    on ``bisect_alloc``'s padding, so its solves share no one shape."""
+    launch = _solve_launch(name, intra_backend, warm_start, n, k, batch)
+    return launch and launch[:2]
+
+
+def launch_tile(name: str, *, intra_backend: str = "reference",
+                warm_start: bool = False, n: int, k: int, batch: int = 1
+                ) -> int | None:
+    """Rows each grid step of that launch takes; None where no kernel runs
+    or the solves share no one shape."""
+    launch = _solve_launch(name, intra_backend, warm_start, n, k, batch)
+    return launch and launch[2]
+
+
+def _solve_launch(name, intra_backend, warm_start, n, k, batch):
+    """(rows, lanes, row tile) behind ``launch_shape`` and ``launch_tile``
+    (tests/test_obs.py checks each combination against the traced step)."""
+    from repro.kernels import market_clear, tiling
 
     if intra_backend == "reference" or name == "ec":
-        return n, k
+        return batch * n, k, None
     if name == "coop" and intra_backend == "megakernel":
-        return tiling.padded_shape(n, k, market_clear.TILE_N)
+        # One grid step a set: it loops over its own tiles inside.
+        rows, lanes = tiling.padded_shape(n, k, market_clear.TILE_N)
+        return batch * rows, lanes, rows
     if name == "coop" and not warm_start:
         return None
-    # bisect_alloc's tile, which dual_demand and mbdf_demand share
-    # (tests/test_obs.py checks each combination against the traced step).
-    return tiling.padded_shape(n, k, bisect_alloc.TILE_N)
+    rows, lanes = tiling.padded_shape(batch * n, k)
+    return rows, lanes, tiling.row_tile(batch * n)
 
 
 # ---------------------------------------------------------------------------

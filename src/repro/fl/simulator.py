@@ -705,10 +705,11 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
     up to and including the period in which its last service finishes
     (``periods``); a chunk is live while any of its episodes is.  Live rows
     are active services and live lanes their enrolled clients, summed over
-    episode-periods.  ``rows`` x ``lanes`` is the shape the policy's solves
-    run on per episode and period (``policy.launch_shape``; None where they
-    share none).  Each waste is counted within the one before it, so the
-    three shares multiply to live lanes over every lane launched:
+    episode-periods.  ``rows`` x ``lanes`` is the shape of one launch of the
+    policy's solves over a chunk's episodes in one period, in grid steps of
+    ``row_tile`` rows (``policy.launch_shape`` and ``launch_tile``; None
+    where they share none).  Each waste is counted within the one before
+    it, so the three shares multiply to live lanes over every lane launched:
     ``chunk_live_periods / step_launches`` (periods),
     ``live_rows / rows_in_live_chunks`` (rows while the chunk is live) and
     ``live_lanes / lanes_of_live_rows`` (lanes of active services).
@@ -727,10 +728,9 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
     periods, n_active, n_clients = (
         np.pad(np.asarray(x, np.int64), (0, pad), mode="edge")
         for x in (periods, n_active, n_clients))
-    rows, lanes = policy_mod.launch_shape(
-        cfg.policy, intra_backend=cfg.intra_backend,
-        warm_start=cfg.warm_start, n=cfg.n_services_total,
-        k=_k_cap(cfg)) or (None, None)
+    shape = dict(intra_backend=cfg.intra_backend, warm_start=cfg.warm_start,
+                 n=cfg.n_services_total, k=_k_cap(cfg), batch=chunk)
+    rows, lanes = policy_mod.launch_shape(cfg.policy, **shape) or (None, None)
     chunk_live = int(periods.reshape(-1, chunk).max(axis=1).sum())
     live_rows = int(n_active.sum())
     return {
@@ -741,9 +741,9 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
         "live_periods": int(periods.sum()),
         "chunk_live_periods": chunk_live,
         "rows": rows, "lanes": lanes,
+        "row_tile": policy_mod.launch_tile(cfg.policy, **shape),
         "live_rows": live_rows, "live_lanes": int(n_clients.sum()),
-        "rows_in_live_chunks": None if rows is None
-        else chunk_live * chunk * rows,
+        "rows_in_live_chunks": None if rows is None else chunk_live * rows,
         "lanes_of_live_rows": None if lanes is None else live_rows * lanes,
     }
 

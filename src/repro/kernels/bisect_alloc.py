@@ -6,9 +6,10 @@ services via fixed-trip bisection and emits both the optimal round time t*_n
 and the per-client water-filling split b_{n,k}.  At production scale the
 operator re-solves this for every active service each period (and inside
 every DISBA dual iteration), so N reaches 1e5-1e6 service-solves per second
-fleet-wide: a (TILE_N, K) tile keeps all 48 bisection trips in VMEM/VREGs
-with zero HBM traffic beyond the initial load -- the kernel is compute-bound
-on the VPU by design (roofline analysis in EXPERIMENTS.md §Perf).
+fleet-wide: a (tile, K) row block (``tiling.row_tile``) keeps all 48
+bisection trips in VMEM/VREGs with zero HBM traffic beyond the initial load
+-- the kernel is compute-bound on the VPU by design (roofline analysis in
+EXPERIMENTS.md §Perf).
 
 Padding convention: padded client slots carry alpha = 0 (they contribute 0 to
 every sum and -inf to the t^C max).  K is padded to a lane multiple (128).
@@ -21,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import TILE_N, padded_shape
+from repro.kernels.tiling import fold_rows, padded_shape, row_tile
 
 NEG_INF = -1e30
 TINY = 1e-30
@@ -62,19 +63,25 @@ def _bisect_kernel(alpha_ref, tcomp_ref, b_ref, tstar_ref, balloc_ref, *, iters:
     tstar_ref[...] = jnp.where(b > 0.0, t_star, jnp.full_like(t_star, 1.0 / TINY))
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "tile_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iters", "interpret"))
 def bisect_alloc(
     alpha: jax.Array,    # (N, K) f32, 0 at padded client slots
     t_comp: jax.Array,   # (N, K) f32
     b: jax.Array,        # (N,) f32 per-service bandwidth budget
     *,
     iters: int = 48,
-    tile_n: int = TILE_N,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (t_star (N,), b_alloc (N, K))."""
+    """Returns (t_star (N,), b_alloc (N, K)).  Under vmap, one launch over
+    the rows of the whole batch (``tiling.fold_rows``)."""
+    launch = functools.partial(_launch, iters=iters, interpret=interpret)
+    return fold_rows(launch)(alpha, t_comp, b)
+
+
+def _launch(alpha, t_comp, b, *, iters: int, interpret: bool):
     n, k = alpha.shape
-    n_pad, k_pad = padded_shape(n, k, tile_n)
+    n_pad, k_pad = padded_shape(n, k)
+    tile_n = row_tile(n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))

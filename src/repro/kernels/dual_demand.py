@@ -12,7 +12,7 @@ reference path materializes ~48 masked (N, K) array sweeps per evaluation; at
 one evaluation per dual iteration of every period of every vmapped episode
 this dominates the long-term simulation's allocation cost.
 
-This kernel is the fused fast path: a (TILE_N, K) tile runs the whole
+This kernel is the fused fast path: a (tile, K) row block runs the whole
 fixed-trip price->frequency bisection in VMEM/VREGs and emits BOTH the
 per-service demand b_n(lam) and its closed-form slope db_n/dlam (Lemma 1 /
 Eqns. 9-10 via psi(f) = f'/(1+f)) in a single launch, so a safeguarded-Newton
@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import TILE_N, padded_shape
+from repro.kernels.tiling import fold_rows, padded_shape, row_tile
 
 NEG_INF = -1e30
 TINY = 1e-30
@@ -104,20 +104,27 @@ def _dual_demand_kernel(alpha_ref, tcomp_ref, lam_ref, b_ref, slope_ref, *,
     slope_ref[...] = slope
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "tile_n", "interpret"))
+@functools.partial(jax.jit, static_argnames=("iters", "interpret"))
 def dual_demand(
     alpha: jax.Array,    # (N, K) f32, 0 at padded client slots
     t_comp: jax.Array,   # (N, K) f32
     lam: jax.Array,      # scalar or (N,) f32 dual price
     *,
     iters: int = 48,
-    tile_n: int = TILE_N,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (b (N,), db/dlam (N,)) -- per-service demand and slope."""
+    """Returns (b (N,), db/dlam (N,)) -- per-service demand and slope.
+    Under vmap, one launch over the rows of the whole batch
+    (``tiling.fold_rows``)."""
+    lam = jnp.broadcast_to(jnp.asarray(lam, jnp.float32), alpha.shape[:1])
+    launch = functools.partial(_launch, iters=iters, interpret=interpret)
+    return fold_rows(launch)(alpha, t_comp, lam)
+
+
+def _launch(alpha, t_comp, lam, *, iters: int, interpret: bool):
     n, k = alpha.shape
-    lam = jnp.broadcast_to(jnp.asarray(lam, jnp.float32), (n,))
-    n_pad, k_pad = padded_shape(n, k, tile_n)
+    n_pad, k_pad = padded_shape(n, k)
+    tile_n = row_tile(n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))

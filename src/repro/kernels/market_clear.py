@@ -33,8 +33,8 @@ delegates to the reference solver itself.
 
 ``mbdf_demand`` moves the auction's joint (N, M) ``fairness.mbdf_grid``
 bisection onto the same tiling conventions: grid (n_tiles,), each launch
-step solving all M price columns of a (TILE_N, M) block against its
-(TILE_N, K) service tile, so services stream from HBM once, not M times.
+step solving all M price columns of a (tile, M) block against its
+(tile, K) service block, so services stream from HBM once, not M times.
 
 Padding conventions match ``bisect_alloc``/``dual_demand``: padded client
 slots carry alpha = 0, K pads to the 128-lane multiple, N to the tile.
@@ -53,8 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.dual_demand import (
     F_CEIL, NEG_INF, TINY, demand_slope_tile,
 )
-from repro.kernels.tiling import TILE_N as TILE_N_MBDF  # (N, M) mbdf grid
-from repro.kernels.tiling import padded_shape
+from repro.kernels.tiling import fold_rows, padded_shape, row_tile
 
 TILE_N = 128      # row tile of the megakernel's internal loop
 
@@ -215,41 +214,46 @@ def _mbdf_kernel(alpha_ref, tcomp_ref, price_ref, b_ref, *,
     tcomp = jnp.where(valid, tcomp_ref[...], 0.0)
     prices = price_ref[...]                      # (TN, M)
     m = prices.shape[1]
-    cols = [prices[:, j:j + 1] for j in range(m)]
+    # The M columns' per-row values share one (TN, M) array, so each trip's
+    # per-row arithmetic takes the vregs of one column, not of M.
+    col = jax.lax.broadcasted_iota(jnp.int32, prices.shape, 1)
 
     asum = jnp.sum(alpha, axis=1, keepdims=True)
     tcmax = jnp.max(jnp.where(valid, tcomp, NEG_INF), axis=1, keepdims=True)
     active = asum > 0.0
     f_hi = jnp.where(active, F_CEIL / jnp.maximum(tcmax, TINY), 0.0)
 
-    def q_at(f):
-        one_m = jnp.maximum(1.0 - tcomp * f, TINY)
-        s = jnp.sum(alpha / (one_m * one_m), axis=1, keepdims=True)
+    def q_at(f):                                 # (TN, M) -> (TN, M)
+        s = jnp.zeros_like(f)
+        for j in range(m):
+            one_m = jnp.maximum(1.0 - tcomp * f[:, j:j + 1], TINY)
+            s = jnp.where(col == j, jnp.sum(alpha / (one_m * one_m), axis=1,
+                                            keepdims=True), s)
         # q(f) = g'(b) at f: [(1-a) + a/(1+f)] * f*'(b)  (Eq. 21 derivative)
         return ((1.0 - alpha_fair) + alpha_fair / (1.0 + f)) \
             * (1.0 / jnp.maximum(s, TINY))
 
     def body(_, carry):
-        # All M price columns bisect together: one (lo, hi) pair per column.
-        out = []
-        for price, (lo, hi) in zip(cols, carry):
-            f = 0.5 * (lo + hi)
-            go_right = (q_at(f) - price) > 0.0   # q decreasing in f
-            out.append((jnp.where(go_right, f, lo), jnp.where(go_right, hi, f)))
-        return tuple(out)
+        # All M price columns bisect together, each in its own lane of the
+        # (TN, M) brackets.
+        lo, hi = carry
+        f = 0.5 * (lo + hi)
+        go_right = (q_at(f) - prices) > 0.0      # q decreasing in f
+        return jnp.where(go_right, f, lo), jnp.where(go_right, hi, f)
 
-    lo0 = jnp.zeros_like(f_hi)
-    brackets = jax.lax.fori_loop(0, iters, body,
-                                 tuple((lo0, f_hi) for _ in range(m)))
+    lo, hi = jax.lax.fori_loop(
+        0, iters, body,
+        (jnp.zeros_like(prices), jnp.broadcast_to(f_hi, prices.shape)))
 
     p_max = jnp.where(active, 1.0 / jnp.maximum(asum, TINY), 0.0)
-    for j, (price, (lo, hi)) in enumerate(zip(cols, brackets)):
-        f = jnp.where(price >= p_max, 0.0, 0.5 * (lo + hi))
-        one_m = jnp.maximum(1.0 - tcomp * f, TINY)
-        b_ref[:, j:j + 1] = jnp.sum(alpha * f / one_m, axis=1, keepdims=True)
+    f = jnp.where(prices >= p_max, 0.0, 0.5 * (lo + hi))
+    for j in range(m):
+        f_j = f[:, j:j + 1]
+        one_m = jnp.maximum(1.0 - tcomp * f_j, TINY)
+        b_ref[:, j:j + 1] = jnp.sum(alpha * f_j / one_m, axis=1, keepdims=True)
 
 
-@functools.partial(jax.jit, static_argnames=("alpha_fair", "iters", "tile_n",
+@functools.partial(jax.jit, static_argnames=("alpha_fair", "iters",
                                              "interpret"))
 def mbdf_demand(
     alpha: jax.Array,    # (N, K) f32, 0 at padded client slots
@@ -258,20 +262,28 @@ def mbdf_demand(
     alpha_fair: float,
     *,
     iters: int = 48,
-    tile_n: int = TILE_N_MBDF,
     interpret: bool = False,
 ) -> jax.Array:
     """Modified bandwidth demand d_n(p_m) at the whole (N, M) grid -> (N, M).
 
-    Grid (n_tiles,): each step takes one (TILE_N, K) service tile with its
-    whole (TILE_N, M) price block, so the tile streams from HBM once and all
+    Grid (n_tiles,): each step takes one (tile, K) service block with its
+    whole (tile, M) price block, so the block streams from HBM once and all
     M joint bisections run on it in VMEM.  The price and output blocks span
     the full M axis, which is what Mosaic requires of a last block dimension
-    that is not a multiple of 128.
+    that is not a multiple of 128.  Under vmap, one launch over the rows of
+    the whole batch (``tiling.fold_rows``).
     """
+    launch = functools.partial(_mbdf_launch, alpha_fair=alpha_fair,
+                               iters=iters, interpret=interpret)
+    return fold_rows(launch)(alpha, t_comp, prices)
+
+
+def _mbdf_launch(alpha, t_comp, prices, *, alpha_fair: float, iters: int,
+                 interpret: bool):
     n, k = alpha.shape
     m = prices.shape[1]
-    n_pad, k_pad = padded_shape(n, k, tile_n)
+    n_pad, k_pad = padded_shape(n, k)
+    tile_n = row_tile(n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))

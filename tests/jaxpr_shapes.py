@@ -1,17 +1,24 @@
-"""The shapes a traced function hands ``pallas_call``, read off its jaxpr."""
+"""The launches a traced function hands ``pallas_call``, read off its jaxpr."""
+import math
+
 import jax
 
 
-def pallas_input_shapes(fn, *args):
-    """Shape of the first operand of every ``pallas_call`` in fn's jaxpr."""
-    shapes = []
+def pallas_launches(fn, *args):
+    """(rows, lanes, row block) of every ``pallas_call`` in fn's jaxpr: its
+    first operand as rows of lanes (a vmapped launch that is not folded
+    counts each batch element's rows), and the rows a grid step takes."""
+    launches = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                shapes.append(tuple(eqn.invars[0].aval.shape))
+                shape = eqn.invars[0].aval.shape
+                mapping = eqn.params["grid_mapping"].block_mappings[0]
+                launches.append((math.prod(shape[:-1]), shape[-1],
+                                 mapping.block_shape[-2].block_size))
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub)
 
     walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return shapes
+    return launches

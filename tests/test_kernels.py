@@ -117,35 +117,169 @@ def test_bisect_alloc_budget_and_equalization():
     )
 
 
+@pytest.mark.parametrize("batch", [None, 64])
 @pytest.mark.parametrize("kernel", ["bisect_alloc", "mbdf_demand",
                                     "dual_demand", "market_clear"])
 @pytest.mark.parametrize("n,k", [(10, 48), (8, 128), (3, 130), (129, 7)])
-def test_padded_shape_is_what_each_wrapper_launches(kernel, n, k):
+def test_padded_shape_is_what_each_wrapper_launches(kernel, n, k, batch):
     """``tiling.padded_shape`` is the (rows, lanes) every wrapper hands
-    ``pallas_call``; ``policy.launch_shape`` reports it for the fleet
-    engine's work counts."""
-    from jaxpr_shapes import pallas_input_shapes
+    ``pallas_call``, in grid steps of ``tiling.row_tile`` rows;
+    ``policy.launch_shape`` reports it for the fleet engine's work counts.
+    Under vmap the three bisection kernels launch once on the folded rows
+    of the whole batch; the megakernel launches each set on its own."""
+    from jaxpr_shapes import pallas_launches
 
     from repro.kernels import dual_demand, market_clear
-    from repro.kernels.tiling import padded_shape
+    from repro.kernels.tiling import padded_shape, row_tile
 
-    a = jnp.ones((n, k), jnp.float32)
+    lead = () if batch is None else (batch,)
+    a = jnp.ones(lead + (n, k), jnp.float32)
     calls = {
-        "bisect_alloc": (lambda: bisect_alloc(a, a, jnp.ones((n,)),
-                                              interpret=True),
-                         padded_shape(n, k)),
-        "mbdf_demand": (lambda: market_clear.mbdf_demand(
-            a, a, jnp.ones((n, 5)), 0.5, interpret=True),
-            padded_shape(n, k, market_clear.TILE_N_MBDF)),
-        "dual_demand": (lambda: dual_demand.dual_demand(a, a, 1.0,
-                                                        interpret=True),
-                        padded_shape(n, k, dual_demand.TILE_N)),
-        "market_clear": (lambda: market_clear.market_clear(
+        "bisect_alloc": lambda a: bisect_alloc(a, a, jnp.ones(a.shape[:1]),
+                                               interpret=True),
+        "mbdf_demand": lambda a: market_clear.mbdf_demand(
+            a, a, jnp.ones((a.shape[0], 5)), 0.5, interpret=True),
+        "dual_demand": lambda a: dual_demand.dual_demand(a, a, 1.0,
+                                                         interpret=True),
+        "market_clear": lambda a: market_clear.market_clear(
             a, a, 10.0, 0.0, interpret=True),
-            padded_shape(n, k, market_clear.TILE_N)),
     }
-    fn, want = calls[kernel]
-    assert pallas_input_shapes(fn) == [want]
+    fn = calls[kernel] if batch is None else jax.vmap(calls[kernel])
+    rows = (batch or 1) * n
+    if kernel == "market_clear":
+        per_set, lanes = padded_shape(n, k, market_clear.TILE_N)
+        want = ((batch or 1) * per_set, lanes, per_set)
+    else:
+        want = padded_shape(rows, k) + (row_tile(rows),)
+    assert pallas_launches(fn, a) == [want]
+
+
+def test_row_tile_pads_under_a_sublane_group_per_grid_step():
+    """The fewest grid steps of at most ``ROW_CAP`` rows, split evenly:
+    every step pads under 8 rows, and a launch over whole blocks pads
+    none."""
+    from repro.kernels.tiling import ROW_CAP, padded_shape, row_tile
+
+    for rows in list(range(1, 2 * ROW_CAP + 20)) + [640, 4096, 8256]:
+        tile = row_tile(rows)
+        padded, _ = padded_shape(rows, 1)
+        steps = padded // tile
+        assert tile % 8 == 0 and tile <= ROW_CAP
+        assert steps == -(-rows // ROW_CAP)
+        assert 0 <= padded - rows < 8 * steps
+    assert row_tile(10) == 16 and padded_shape(10, 48) == (16, 128)
+    assert padded_shape(640, 48)[0] == 640
+
+
+def _services(rng, lead, n, k):
+    """Ragged service sets with a fully-inactive row, masked slots slower
+    than every valid client, and the third operand of each kernel: a
+    budget per row (some 0), an ascending 5-bid price grid around each
+    row's p_max (some above it), and a dual price per row."""
+    shape = lead + (n, k)
+    count = rng.integers(1, k + 1, size=lead + (n, 1))
+    count[..., 0, :] = 0
+    mask = np.arange(k) < count
+    alpha = np.where(mask, rng.uniform(0.01, 0.3, shape), 0.0)
+    t_comp = np.where(mask, rng.uniform(0.01, 0.06, shape), 99.0)
+    p_max = 1.0 / np.maximum(alpha.sum(-1, keepdims=True), 1e-3)
+    prices = p_max * np.sort(rng.uniform(0.05, 1.2, lead + (n, 5)), -1)
+    budget = np.where(rng.uniform(size=lead + (n,)) < 0.2, 0.0,
+                      rng.uniform(0.2, 4.0, lead + (n,)))
+    lam = rng.uniform(0.05, 0.5, lead + (n,))
+    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
+    return f32(alpha), f32(t_comp), {"bisect_alloc": f32(budget),
+                                     "mbdf_demand": f32(prices),
+                                     "dual_demand": f32(lam)}
+
+
+def _folded_kernel(kernel):
+    from repro.kernels import dual_demand, market_clear
+
+    return {
+        "bisect_alloc": lambda a, t, x: bisect_alloc(a, t, x, interpret=True),
+        "mbdf_demand": lambda a, t, x: market_clear.mbdf_demand(
+            a, t, x, 0.5, interpret=True),
+        "dual_demand": lambda a, t, x: dual_demand.dual_demand(
+            a, t, x, interpret=True),
+    }[kernel]
+
+
+def _assert_bitwise(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _stacked_loop(fn, *args, in_axes):
+    """The unbatched calls, one per batch element, stacked."""
+    size = next(x.shape[0] for x, ax in zip(args, in_axes) if ax == 0)
+    outs = [fn(*(x[i] if ax == 0 else x for x, ax in zip(args, in_axes)))
+            for i in range(size)]
+    return jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
+
+
+FOLDED = ["bisect_alloc", "mbdf_demand", "dual_demand"]
+
+
+@pytest.mark.parametrize("kernel", FOLDED)
+@pytest.mark.parametrize("e,n,k", [(64, 10, 48), (3, 129, 7)])
+def test_vmapped_wrapper_is_bitwise_a_loop_of_unbatched_calls(kernel, e, n,
+                                                              k):
+    """The fold changes which rows share a launch, never a row's result:
+    each row's arithmetic is its own.  The vmapped call is one launch over
+    the folded padding of all e * n rows."""
+    from jaxpr_shapes import pallas_launches
+
+    from repro.kernels.tiling import padded_shape, row_tile
+
+    fn = _folded_kernel(kernel)
+    alpha, t_comp, third = _services(np.random.default_rng(e * n + k), (e,),
+                                     n, k)
+    args = (alpha, t_comp, third[kernel])
+    _assert_bitwise(jax.vmap(fn)(*args),
+                    _stacked_loop(fn, *args, in_axes=(0, 0, 0)))
+    assert pallas_launches(jax.vmap(fn), *args) == [
+        padded_shape(e * n, k) + (row_tile(e * n),)]
+
+
+@pytest.mark.parametrize("kernel", FOLDED)
+def test_vmapped_wrapper_broadcasts_an_unbatched_operand(kernel):
+    """A budget, price grid or dual price shared by every batch element
+    (``in_axes=None``) is broadcast into the fold; a scalar dual price
+    too, shared or one per batch element."""
+    fn = _folded_kernel(kernel)
+    alpha, t_comp, third = _services(np.random.default_rng(5), (4,), 10, 48)
+    shared = third[kernel][0]
+    _assert_bitwise(jax.vmap(fn, in_axes=(0, 0, None))(alpha, t_comp, shared),
+                    _stacked_loop(fn, alpha, t_comp, shared,
+                                  in_axes=(0, 0, None)))
+    if kernel == "dual_demand":
+        lam = jnp.float32(0.2)
+        _assert_bitwise(jax.vmap(fn, in_axes=(0, 0, None))(alpha, t_comp, lam),
+                        _stacked_loop(fn, alpha, t_comp, lam,
+                                      in_axes=(0, 0, None)))
+        lams = jnp.float32([0.1, 0.2, 0.3, 0.4])
+        _assert_bitwise(jax.vmap(fn)(alpha, t_comp, lams),
+                        _stacked_loop(fn, alpha, t_comp, lams,
+                                      in_axes=(0, 0, 0)))
+
+
+@pytest.mark.parametrize("kernel", FOLDED)
+def test_nested_vmap_folds_level_by_level_into_one_launch(kernel):
+    """vmap of vmap: the inner batch folds into the rows, then the outer
+    one, so the call is still one launch over every row."""
+    from jaxpr_shapes import pallas_launches
+
+    from repro.kernels.tiling import padded_shape, row_tile
+
+    fn = _folded_kernel(kernel)
+    alpha, t_comp, third = _services(np.random.default_rng(6), (2, 3), 10, 48)
+    args = (alpha, t_comp, third[kernel])
+    nested = jax.vmap(jax.vmap(fn))
+    want = _stacked_loop(jax.vmap(fn), *args, in_axes=(0, 0, 0))
+    _assert_bitwise(nested(*args), want)
+    assert pallas_launches(nested, *args) == [
+        padded_shape(60, 48) + (row_tile(60),)]
 
 
 # ---------------------------------------------------------------------------

@@ -3,10 +3,11 @@ each ``run_fleet`` call.
 
 * spans time their block, the call log is bounded, counters add, and a
   fresh ``jit`` adds to the compile seconds;
-* ``policy.launch_shape`` is the shape the traced policy step hands its
-  kernels, under every policy and backend;
+* ``policy.launch_shape`` and ``launch_tile`` are the launch the traced,
+  vmapped policy step hands its kernels, under every policy and backend;
 * ``run_fleet``'s work counts equal a recount from ``run_batch``'s history
-  over the padded fleet, with rows and lanes from the traced step;
+  over the padded fleet, with rows, lanes and row block from the traced
+  step;
 * a profiler capture of one ``run_fleet`` call holds its ``repro.fleet.*``
   spans, nested, on a host plane.
 """
@@ -16,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jaxpr_shapes import pallas_input_shapes
+from jaxpr_shapes import pallas_launches
 
 from repro import obs
 from repro.compat import flat_mesh
@@ -105,38 +106,49 @@ def test_compile_seconds_is_the_union_of_nested_events(monkeypatch):
     assert obs.compile_seconds(until=0.5) == 0.0
 
 
-def _step_shapes(policy, backend, warm, n, k):
-    """The set of shapes the policy's traced step hands ``pallas_call``."""
+def _step_launches(policy, backend, warm, n, k, batch):
+    """The set of (rows, lanes, row block) the policy's step, vmapped over
+    ``batch`` service sets as the fleet engine runs it, hands
+    ``pallas_call``."""
     pol = policy_mod.get_stateful_policy(policy, warm_start=warm,
                                          intra_backend=backend)
-    ones = jnp.ones((n, k), jnp.float32)
+    ones = jnp.ones((batch, n, k), jnp.float32)
     svc = ServiceSet(alpha=ones, t_comp=ones, mask=ones > 0)
-    return set(pallas_input_shapes(
-        lambda s: pol.step(s, jnp.float32(10.0), pol.init_state(n)), svc))
+    step = jax.vmap(lambda s: pol.step(s, jnp.float32(10.0),
+                                       pol.init_state(n)))
+    return set(pallas_launches(step, svc))
 
 
+@pytest.mark.parametrize("batch", [1, simulator.FLEET_CHUNK])
 @pytest.mark.parametrize("policy,warm", [(p, False) for p in
                                          simulator.POLICIES]
                          + [("coop", True)])
 @pytest.mark.parametrize("backend", policy_mod.INTRA_BACKENDS)
 def test_launch_shape_is_what_the_policy_step_launches(policy, warm,
-                                                       backend):
-    """At the benchmark cell's (10, 48): the kernels' padding, the set
-    itself where no kernel runs, and None only for cold coop on ``pallas``,
-    whose dual bisection runs on the reference beside a kernel f*(b)."""
+                                                       backend, batch):
+    """At the benchmark cell's (10, 48), at one episode a launch and at the
+    fleet's chunk: the kernels' padding of the folded rows, the sets
+    themselves where no kernel runs, and None only for cold coop on
+    ``pallas``, whose dual bisection runs on the reference beside a kernel
+    f*(b).  ``launch_tile`` is the rows a grid step takes."""
     n, k = 10, 48
-    shapes = _step_shapes(policy, backend, warm, n, k)
-    got = policy_mod.launch_shape(policy, intra_backend=backend,
-                                  warm_start=warm, n=n, k=k)
+    launches = _step_launches(policy, backend, warm, n, k, batch)
+    shape = dict(intra_backend=backend, warm_start=warm, n=n, k=k,
+                 batch=batch)
+    got = policy_mod.launch_shape(policy, **shape)
+    tile = policy_mod.launch_tile(policy, **shape)
     if (policy, backend, warm) == ("coop", "pallas", False):
-        assert got is None and shapes
+        assert got is None and tile is None and launches
+    elif launches:
+        assert {got + (tile,)} == launches
     else:
-        assert {got} == (shapes or {(n, k)})
+        assert got == (batch * n, k) and tile is None
 
 
 def _recount(cfg, seeds, chunk, n_dev=1):
     """The work counts, recounted from ``run_batch``'s per-period history
-    of the padded fleet, with rows and lanes from the traced step."""
+    of the padded fleet, with rows, lanes and row block from the step
+    traced at the chunk."""
     per_dev = -(-len(seeds) // n_dev)
     n_chunks = -(-per_dev // chunk)
     padded = seeds + [seeds[-1]] * (n_dev * n_chunks * chunk - len(seeds))
@@ -146,10 +158,10 @@ def _recount(cfg, seeds, chunk, n_dev=1):
     periods = np.array([list(row).index(True) + 1 if row.any() else T
                         for row in h["all_done"]])
     live = np.arange(T)[None, :] < periods[:, None]
-    (rows, lanes), = (_step_shapes(cfg.policy, cfg.intra_backend,
-                                   cfg.warm_start, cfg.n_services_total,
-                                   cfg.k_max)
-                      or {(cfg.n_services_total, cfg.k_max)})
+    n, k = cfg.n_services_total, cfg.k_max
+    (rows, lanes, tile), = (_step_launches(cfg.policy, cfg.intra_backend,
+                                           cfg.warm_start, n, k, chunk)
+                            or {(chunk * n, k, None)})
     chunk_max = [max(periods[i:i + chunk]) for i in range(0, len(padded),
                                                          chunk)]
     live_rows = int((h["n_active"] * live).sum())
@@ -160,10 +172,10 @@ def _recount(cfg, seeds, chunk, n_dev=1):
         "scanned_periods": len(padded) * T,
         "live_periods": int(periods.sum()),
         "chunk_live_periods": int(sum(chunk_max)),
-        "rows": rows, "lanes": lanes,
+        "rows": rows, "lanes": lanes, "row_tile": tile,
         "live_rows": live_rows,
         "live_lanes": int((h["n_clients"] * live).sum()),
-        "rows_in_live_chunks": int(sum(chunk_max)) * chunk * rows,
+        "rows_in_live_chunks": int(sum(chunk_max)) * rows,
         "lanes_of_live_rows": live_rows * lanes,
     }
 
@@ -175,8 +187,9 @@ def _recount(cfg, seeds, chunk, n_dev=1):
 def test_fleet_work_equals_a_recount_from_batch_history(policy, backend, warm,
                                                         collect_history):
     """Fleet of 5 on chunk 2: a remainder chunk and one pad episode, whose
-    work counts as the devices ran it.  The megakernel pads rows to its own
-    tile of 128, not the 8 of the other kernels."""
+    work counts as the devices ran it.  The megakernel pads each episode's
+    rows to its own tile of 128; the other kernels pad the chunk's folded
+    rows once."""
     cfg = _cfg(policy=policy, intra_backend=backend, warm_start=warm,
                collect_history=collect_history)
     seeds = [3, 8, 13, 21, 34]
@@ -195,7 +208,7 @@ def test_fleet_work_equals_a_recount_from_batch_history(policy, backend, warm,
               * work["live_rows"] / work["rows_in_live_chunks"]
               * work["live_lanes"] / work["lanes_of_live_rows"])
     assert shares == pytest.approx(work["live_lanes"] / (
-        work["scanned_periods"] * work["rows"] * work["lanes"]))
+        work["step_launches"] * work["rows"] * work["lanes"]))
     assert work["call_s"] >= work["prepare_s"] + work["device_s"] \
         + work["collect_s"] > 0
 

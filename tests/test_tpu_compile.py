@@ -71,3 +71,20 @@ def test_bisect_alloc_compiles(one_chip):
     text = _compiled_text(lambda a, t, b: bisect_alloc(a, t, b),
                           one_chip, (8192, 128), (8192, 128), (8192,))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernel", ["mbdf_demand", "bisect_alloc"])
+def test_vmapped_launch_compiles_as_one_kernel_at_the_sweep_cell(one_chip,
+                                                                 kernel):
+    """The selfish sweep's launch: 64 episodes of (10, 48) services (5 bids)
+    vmapped, folded into one kernel over 640 rows, whose row blocks Mosaic
+    takes inside the default scoped VMEM."""
+    e, n, k = 64, 10, 48
+    if kernel == "mbdf_demand":
+        fn = jax.vmap(lambda a, t, p: mbdf_demand(a, t, p, 0.5))
+        last = (e, n, 5)
+    else:
+        fn = jax.vmap(lambda a, t, b: bisect_alloc(a, t, b))
+        last = (e, n)
+    text = _compiled_text(fn, one_chip, (e, n, k), (e, n, k), last)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1

@@ -14,7 +14,10 @@ Parameters, from the traffic file:
 Each call takes a fresh block of episode seeds drawn from ``--seed``.  The
 window makes whole calls until ``--seconds`` have passed;
 ``episodes_per_s`` is the episodes of those calls over their time.  A
-traced run profiles the window's first call.
+traced run profiles the window's first call, made of one chunk of episodes
+a chip (the fleet engine's unit: the same launches, a quarter of the
+operations of a whole call, which a profiler's buffers hold); set-up warms
+that shape only in a traced run.
 ``correct`` replays the drawn episodes with the plain reference: arrivals,
 cohorts and every period's services from the episode's key, the policy's
 reference allocation each period, and rounds counted from its frequencies.
@@ -43,9 +46,9 @@ class SweepRun:
         self.calls: list[dict] = []
         self.call_s: list[float] = []
 
-    def _seeds(self) -> list[int]:
-        return [int(s) for s in self.rng.choice(2 ** 31, self.per_call,
-                                                replace=False)]
+    def _seeds(self, n: int | None = None) -> list[int]:
+        return [int(s) for s in self.rng.choice(
+            2 ** 31, n or self.per_call, replace=False)]
 
     def _sim_config(self):
         from repro.fl import simulator
@@ -70,7 +73,7 @@ class SweepRun:
 
         return simulator.run_fleet(self.sim, seeds, self.net, mesh=self.mesh)
 
-    def setup(self) -> None:
+    def setup(self, tracer) -> None:
         from repro.core import network
         from repro.fl import simulator
         from repro.launch.mesh import make_fleet_mesh
@@ -78,7 +81,12 @@ class SweepRun:
         self.net = network.NetworkConfig(**self.cfg["network"])
         self.sim = self._sim_config()
         self.mesh = make_fleet_mesh(len(self.devices))
-        self._call(self._seeds())
+        seeds = self._seeds()
+        out = self._call(seeds)
+        self.chunk = int(out["fleet"]["chunk"])
+        self.traced_call = self.chunk * len(self.devices)
+        if tracer.enabled:
+            self._call(seeds[:self.traced_call])
         self.traces_before = simulator.trace_count()
 
     def window(self, seconds: float, tracer) -> None:
@@ -86,8 +94,9 @@ class SweepRun:
 
         t_end = time.perf_counter() + seconds
         while time.perf_counter() < t_end:
-            seeds = self._seeds()
             first = not self.calls
+            seeds = self._seeds(self.traced_call if first and tracer.enabled
+                                else None)
             if first:
                 tracer.start()      # a traced run profiles the first call
             t0 = time.perf_counter()
@@ -96,7 +105,6 @@ class SweepRun:
             self.call_s.append(time.perf_counter() - t0)
             if first:
                 tracer.stop()
-            self.chunk = int(out["fleet"]["chunk"])
             self.calls.append({"seeds": seeds,
                                "durations": np.asarray(out["durations"]),
                                "finished": np.asarray(out["finished"]),
@@ -107,8 +115,7 @@ class SweepRun:
                                f"times inside the window")
 
     def end_to_end(self) -> dict:
-        return {"episodes_per_s": self.per_call * len(self.calls)
-                / sum(self.call_s)}
+        return {"episodes_per_s": self.attempted / sum(self.call_s)}
 
     def readings(self) -> Readings:
         """Counters of the window, and the shapes of one launch of each
@@ -122,11 +129,12 @@ class SweepRun:
                       "useful_period_share": float(np.mean(
                           periods / int(self.cfg["max_periods"])))},
             kernel_calls={"mbdf_demand": dict(n=rows, k=k, m=m, iters=48),
-                          "bisect_alloc": dict(n=rows, k=k, iters=48)})
+                          "bisect_alloc": dict(n=rows, k=k, iters=48),
+                          "dual_demand": dict(n=rows, k=k)})
 
     @property
     def attempted(self) -> int:
-        return self.per_call * len(self.calls)
+        return sum(len(c["seeds"]) for c in self.calls)
 
     @property
     def failed(self) -> int:
@@ -142,14 +150,14 @@ class SweepRun:
     def _chosen(self):
         """The window's episodes that ``correct`` compares, from the seed."""
         pick = np.random.default_rng([self.cell.seed, 4])
-        flat = [(i, j) for i in range(len(self.calls))
-                for j in range(self.per_call)]
+        flat = [(i, j) for i, c in enumerate(self.calls)
+                for j in range(len(c["seeds"]))]
         n_check = min(int(self.tr["check_episodes"]), len(flat))
         return [flat[int(k)] for k in pick.choice(len(flat), n_check,
                                                   replace=False)]
 
     def check(self, reference) -> dict:
-        print(f"[sweep] {len(self.calls)} calls of {self.per_call} episodes, "
+        print(f"[sweep] {len(self.calls)} calls, {self.attempted} episodes, "
               f"{sum(self.call_s):.3f} s in all", flush=True)
         chosen = self._chosen()
         dur = np.stack([self.calls[i]["durations"][j] for i, j in chosen])

@@ -17,9 +17,9 @@ name:
 * ``bench/costs/<kernel>.py``       operations and bytes of a kernel call.
 
 A driver module exposes ``make(cell, devices) -> run`` where ``run`` has
-``setup()``, ``window(seconds, tracer)``, ``end_to_end()``, ``readings()``,
-``attempted``, ``failed``, ``free()``, ``check(reference)`` and
-``control(reference, dtype)``.
+``setup(tracer)``, ``window(seconds, tracer)``, ``end_to_end()``,
+``readings()``, ``attempted``, ``failed``, ``free()``, ``check(reference)``
+and ``control(reference, dtype)``.
 """
 from __future__ import annotations
 
@@ -212,7 +212,7 @@ def drive(cell: Cell, devices, seconds: float, tracer, t_start: float):
     """Set-up and the window: the run, and the set-up's seconds from
     ``t_start``."""
     run = driver_module(cell).make(cell, devices)
-    run.setup()
+    run.setup(tracer)
     setup_s = time.perf_counter() - t_start
     run.window(float(seconds), tracer)
     return run, setup_s

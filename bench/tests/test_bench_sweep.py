@@ -17,7 +17,7 @@ from benchutil import run_cell
 from bench import control, harness, tracing
 from repro.fl import simulator
 
-SWEEP = ["paper_selfish.sweep_fig12"]
+SWEEP = ["paper_selfish.sweep_fig12", "paper_coop.sweep_fig12"]
 
 
 @pytest.mark.parametrize("workload", SWEEP)
@@ -36,13 +36,16 @@ def test_control_in_bfloat16_is_not_correct(tiny, workload):
     assert rows[0]["correct"] and not rows[1]["correct"], rows
 
 
+@pytest.mark.parametrize("workload", SWEEP)
 @pytest.mark.parametrize("fault", [altered, state_unchanged])
-def test_sweep_fault_is_not_correct(tiny, broken, fault):
+def test_sweep_fault_is_not_correct(tiny, broken, fault, workload):
     broken(fault)
-    assert not run_cell(tiny, SWEEP[0], seed=11)["correct"]
+    assert not run_cell(tiny, workload, seed=11)["correct"]
 
 
-def test_sweep_half_the_fleet_left_out_is_not_correct(tiny, monkeypatch):
+@pytest.mark.parametrize("workload", SWEEP)
+def test_sweep_half_the_fleet_left_out_is_not_correct(tiny, monkeypatch,
+                                                      workload):
     original = simulator.run_fleet
 
     def half(cfg, seeds, net=None, **kw):
@@ -53,14 +56,35 @@ def test_sweep_half_the_fleet_left_out_is_not_correct(tiny, monkeypatch):
         return out
 
     monkeypatch.setattr(simulator, "run_fleet", half)
-    assert not run_cell(tiny, SWEEP[0], seed=11)["correct"]
+    assert not run_cell(tiny, workload, seed=11)["correct"]
+
+
+def test_a_traced_window_profiles_one_chunk_a_chip(tiny, monkeypatch):
+    """The profiled call is one chunk of episodes, warmed in set-up so the
+    window traces nothing anew; the calls after it are whole."""
+    class Profiled(tracing.NullTracer):
+        enabled = True
+
+        def start(self):
+            self.started = len(run.calls)
+
+    monkeypatch.setattr(simulator, "FLEET_CHUNK", 8)
+    cell = harness.resolve(tiny, SWEEP[0], 2 ** 31 + 21)
+    run = harness.driver_module(cell).make(cell, jax.devices()[:1])
+    tracer = Profiled()
+    run.setup(tracer)
+    run.window(1.0, tracer)        # raises where the window retraced
+    sizes = [len(c["seeds"]) for c in run.calls]
+    assert tracer.started == 0 and sizes[0] == 8
+    assert len(sizes) > 1 and set(sizes[1:]) == {run.per_call} != {8}
+    assert run.attempted == sum(sizes)
 
 
 def test_mixes_are_deterministic_from_the_seed(tiny):
     def log(workload, seed):
         cell = harness.resolve(tiny, workload, seed)
         run = harness.driver_module(cell).make(cell, jax.devices()[:1])
-        run.setup()
+        run.setup(tracing.NullTracer())
         run.window(0.25, tracing.NullTracer())
         return [c["seeds"] for c in run.calls]
 

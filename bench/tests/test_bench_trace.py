@@ -69,6 +69,65 @@ def test_roofline_share_of_a_kernel():
     assert share(r, "bisect_alloc", "bisect") is None   # nothing to read
 
 
+def test_dual_demand_cost_counts_the_kernel_arithmetic():
+    cell = harness.resolve(ROOT, "paper_coop.sweep_fig12")
+    cost = harness.kernel_cost(cell, "dual_demand").cost
+    flops, nbytes = cost(n=10, k=48, iters=24)
+    assert flops == 10 * (24 * (6 * 48 + 8) + 19 * 48 + 31)
+    assert nbytes == 4 * (2 * 10 * 48 + 3 * 10)
+    assert cost(n=10, k=48, iters=48)[0] > flops
+
+
+def test_dual_demand_roofline_counts_the_launches_of_a_period():
+    """Two periods of the cell's warm dual solve in a synthetic trace: the
+    least time of ``WARM_ITERS`` launches at ``WARM_INNER_ITERS`` trips and
+    one at ``BISECT_ITERS`` over a chunk's 640 rows of 48 clients, twice,
+    over the launches' time; nothing where nothing can be read."""
+    from repro.core.disba import BISECT_ITERS, WARM_INNER_ITERS, WARM_ITERS
+
+    cell = harness.resolve(ROOT, "paper_coop.sweep_fig12")
+    launches = 2 * (WARM_ITERS + 1)
+    ops = {0: [Event("%dual_demand.3", 10.0 * i, 10.0 * i + 2.0)
+               for i in range(launches)] + [Event("%fusion.1", 0.0, 1.0)]}
+    trace = TraceSummary(window=(0.0, 10.0 * launches), ops=ops, modules={},
+                         host=[])
+    r = harness.Readings(trace=trace, cell=cell,
+                         peaks={"flops_per_s": 1e9, "hbm_bytes_per_s": 1e8},
+                         kernel_calls={"dual_demand": dict(n=640, k=48)})
+    cost = harness.kernel_cost(cell, "dual_demand").cost
+
+    def least(iters):
+        flops, nbytes = cost(n=640, k=48, iters=iters)
+        return max(flops / 1e9, nbytes / 1e8)
+
+    want = 100.0 * 2 * (WARM_ITERS * least(WARM_INNER_ITERS)
+                        + least(BISECT_ITERS)) / (2.0 * launches)
+    reader = harness.metric_reader(cell, "dual_demand_roofline")
+    assert reader.read(r) == pytest.approx(want)
+    r.trace = TraceSummary(window=trace.window, ops={0: ops[0][-1:]},
+                           modules={}, host=[])
+    assert reader.read(r) is None            # no launch of the kernel
+    r.trace, r.peaks = trace, None
+    assert reader.read(r) is None            # no peaks off the chip
+
+
+def test_start_hands_the_profiler_its_options(tmp_path, monkeypatch):
+    """No Python tracer, and libtpu's own trace mode: every mode held the
+    same operation events on the chip, so no cell asks for another."""
+    import jax
+
+    given = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, profiler_options: given.append(
+                            profiler_options))
+    tracer = tracing.Tracer(tmp_path, "w", 0, jax.devices()[:1])
+    tracer.start()
+    tracer._window.__exit__(None, None, None)
+    (options,) = given
+    assert options.advanced_configuration == {}
+    assert options.python_tracer_level == 0
+
+
 def test_host_spans_read_back_from_a_recorded_trace(tmp_path):
     import jax
 

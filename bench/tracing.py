@@ -15,6 +15,12 @@ reduction reads the ``.xplane.pb`` file with ``jax.profiler.ProfileData``:
   than ``MAX_OP_EVENTS``); the idle share is 1 - busy / window;
 * a device plane in which the profiler marks dropped buffers fails the
   run: its busy time would count the gap as idle.
+
+The profiler's buffers on a TPU v5e hold about 6.3 million operation
+events, whatever libtpu's ``tpu_trace_mode``, and it takes some 26 us an
+event to stop a capture: a driver keeps its traced work under that (the
+sweep profiles one chunk of episodes a chip), and a capture that still
+overflows fails.
 """
 from __future__ import annotations
 

@@ -177,10 +177,14 @@ def launch_shape(name: str, *, intra_backend: str = "reference",
     themselves where no kernel runs, else the padding of the Pallas
     wrappers the policy calls.  ``bisect_alloc``, ``dual_demand`` and
     ``mbdf_demand`` fold the batch into their rows (``tiling.fold_rows``)
-    and pad the folded rows once; the megakernel is not folded, so each set
-    pads to its own tile of 128.  None for cold ``coop`` on ``pallas``,
-    whose dual bisection runs on the reference at (n, k) and whose f*(b)
-    on ``bisect_alloc``'s padding, so its solves share no one shape."""
+    and pad the folded rows once, each in its own row blocks
+    (``tiling.row_tile``); where the policy's kernels take different
+    blocks, as they do once k spans several lane groups, this is the
+    launch of the one whose grid step holds the most VMEM bytes.  The
+    megakernel is not folded, so each set pads to its own tile of 128.
+    None for cold ``coop`` on ``pallas``, whose dual bisection runs on the
+    reference at (n, k) and whose f*(b) on ``bisect_alloc``'s padding, so
+    its solves share no one shape."""
     launch = _solve_launch(name, intra_backend, warm_start, n, k, batch)
     return launch and launch[:2]
 
@@ -194,21 +198,37 @@ def launch_tile(name: str, *, intra_backend: str = "reference",
     return launch and launch[2]
 
 
+def launch_block_bytes(name: str, *, intra_backend: str = "reference",
+                       warm_start: bool = False, n: int, k: int,
+                       batch: int = 1) -> int | None:
+    """VMEM bytes one grid step of that launch holds, by the account its
+    row tile was fitted to (``tiling.block_bytes``); None where no folded
+    kernel runs (the megakernel holds its whole input at once)."""
+    launch = _solve_launch(name, intra_backend, warm_start, n, k, batch)
+    return launch and launch[3]
+
+
 def _solve_launch(name, intra_backend, warm_start, n, k, batch):
-    """(rows, lanes, row tile) behind ``launch_shape`` and ``launch_tile``
-    (tests/test_obs.py checks each combination against the traced step)."""
-    from repro.kernels import market_clear, tiling
+    """(rows, lanes, row tile, block bytes) behind ``launch_shape``,
+    ``launch_tile`` and ``launch_block_bytes`` (tests/test_obs.py checks
+    each combination against the traced step)."""
+    from repro.kernels import bisect_alloc, dual_demand, market_clear, tiling
 
     if intra_backend == "reference" or name == "ec":
-        return batch * n, k, None
+        return batch * n, k, None, None
     if name == "coop" and intra_backend == "megakernel":
         # One grid step a set: it loops over its own tiles inside.
         rows, lanes = tiling.padded_shape(n, k, market_clear.TILE_N)
-        return batch * rows, lanes, rows
+        return batch * rows, lanes, rows, None
     if name == "coop" and not warm_start:
         return None
-    rows, lanes = tiling.padded_shape(batch * n, k)
-    return rows, lanes, tiling.row_tile(batch * n)
+    # Each kernel the policy launches: its bids or dual demand, then every
+    # policy's f*(b) on bisect_alloc.
+    kernels = {"selfish": (market_clear.mbdf_row_blocks,),
+               "coop": (dual_demand.row_blocks,)}.get(name, ())
+    return max((blocks(batch * n, k)
+                for blocks in kernels + (bisect_alloc.row_blocks,)),
+               key=lambda launch: launch.block_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -708,12 +708,17 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
     episode-periods.  ``rows`` x ``lanes`` is the shape of one launch of the
     policy's solves over a chunk's episodes in one period, in grid steps of
     ``row_tile`` rows (``policy.launch_shape`` and ``launch_tile``; None
-    where they share none).  Each waste is counted within the one before
-    it, so the three shares multiply to live lanes over every lane launched:
+    where they share none), each step holding ``block_bytes`` of the
+    kernels' ``vmem_budget_bytes`` (``policy.launch_block_bytes``; None
+    where no folded kernel runs).  Each waste is counted within the one
+    before it, so the three shares multiply to live lanes over every lane
+    launched:
     ``chunk_live_periods / step_launches`` (periods),
     ``live_rows / rows_in_live_chunks`` (rows while the chunk is live) and
     ``live_lanes / lanes_of_live_rows`` (lanes of active services).
     """
+    from repro.kernels import tiling
+
     if cfg.collect_history:
         done = out["history"]["all_done"]
         periods = np.where(done.any(axis=1), done.argmax(axis=1) + 1,
@@ -731,6 +736,7 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
     shape = dict(intra_backend=cfg.intra_backend, warm_start=cfg.warm_start,
                  n=cfg.n_services_total, k=_k_cap(cfg), batch=chunk)
     rows, lanes = policy_mod.launch_shape(cfg.policy, **shape) or (None, None)
+    block = policy_mod.launch_block_bytes(cfg.policy, **shape)
     chunk_live = int(periods.reshape(-1, chunk).max(axis=1).sum())
     live_rows = int(n_active.sum())
     return {
@@ -742,6 +748,8 @@ def fleet_work(cfg: SimConfig, out: dict, *, n_dev: int, chunk: int,
         "chunk_live_periods": chunk_live,
         "rows": rows, "lanes": lanes,
         "row_tile": policy_mod.launch_tile(cfg.policy, **shape),
+        "block_bytes": block,
+        "vmem_budget_bytes": None if block is None else tiling.VMEM_BUDGET,
         "live_rows": live_rows, "live_lanes": int(n_clients.sum()),
         "rows_in_live_chunks": None if rows is None else chunk_live * rows,
         "lanes_of_live_rows": None if lanes is None else live_rows * lanes,

@@ -6,10 +6,13 @@ services via fixed-trip bisection and emits both the optimal round time t*_n
 and the per-client water-filling split b_{n,k}.  At production scale the
 operator re-solves this for every active service each period (and inside
 every DISBA dual iteration), so N reaches 1e5-1e6 service-solves per second
-fleet-wide: a (tile, K) row block (``tiling.row_tile``) keeps all 48
-bisection trips in VMEM/VREGs with zero HBM traffic beyond the initial load
--- the kernel is compute-bound on the VPU by design (roofline analysis in
-EXPERIMENTS.md §Perf).
+fleet-wide.  A grid step keeps a block of whole rows, all K client slots of
+each, in VMEM through all 48 bisection trips, with zero HBM traffic beyond
+the initial load -- the kernel is compute-bound on the VPU by design
+(roofline analysis in EXPERIMENTS.md §Perf).  The block's rows follow K:
+``row_blocks`` gives the most that fit its ``VMEM_PLANES`` planes of K
+lanes in the VMEM budget (``tiling.row_tile``), so a wider client axis
+takes more, smaller steps.
 
 Padding convention: padded client slots carry alpha = 0 (they contribute 0 to
 every sum and -inf to the t^C max).  K is padded to a lane multiple (128).
@@ -22,10 +25,21 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import fold_rows, padded_shape, row_tile
+from repro.kernels import tiling
 
 NEG_INF = -1e30
 TINY = 1e-30
+# (block, K) float32 planes a grid step holds in VMEM: alpha and t^C in and
+# the split out, each double-buffered (6), and the trip's temporaries (3).
+# The TPU v5e compiler takes 8.2-8.5 planes at 1792 and 4096 lanes.
+VMEM_PLANES = 9
+
+
+def row_blocks(rows: int, k: int) -> tiling.Launch:
+    """The launch ``bisect_alloc`` makes over ``rows`` rows of ``k`` client
+    slots (a vmapped call's rows are the whole batch's): its padded
+    rows and lanes, grid step and VMEM bytes a step."""
+    return tiling.row_blocks(rows, k, VMEM_PLANES)
 
 
 def _bisect_kernel(alpha_ref, tcomp_ref, b_ref, tstar_ref, balloc_ref, *, iters: int):
@@ -75,13 +89,13 @@ def bisect_alloc(
     """Returns (t_star (N,), b_alloc (N, K)).  Under vmap, one launch over
     the rows of the whole batch (``tiling.fold_rows``)."""
     launch = functools.partial(_launch, iters=iters, interpret=interpret)
-    return fold_rows(launch)(alpha, t_comp, b)
+    return tiling.fold_rows(launch)(alpha, t_comp, b)
 
 
 def _launch(alpha, t_comp, b, *, iters: int, interpret: bool):
     n, k = alpha.shape
-    n_pad, k_pad = padded_shape(n, k)
-    tile_n = row_tile(n)
+    blocks = row_blocks(n, k)
+    n_pad, k_pad, tile_n, _ = blocks
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))
@@ -104,6 +118,7 @@ def _launch(alpha, t_comp, b, *, iters: int, interpret: bool):
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         ],
+        compiler_params=blocks.compiler_params,
         interpret=interpret,
     )(alpha.astype(jnp.float32), t_comp.astype(jnp.float32),
       b.astype(jnp.float32)[:, None])

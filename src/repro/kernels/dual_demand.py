@@ -12,9 +12,10 @@ reference path materializes ~48 masked (N, K) array sweeps per evaluation; at
 one evaluation per dual iteration of every period of every vmapped episode
 this dominates the long-term simulation's allocation cost.
 
-This kernel is the fused fast path: a (tile, K) row block runs the whole
-fixed-trip price->frequency bisection in VMEM/VREGs and emits BOTH the
-per-service demand b_n(lam) and its closed-form slope db_n/dlam (Lemma 1 /
+This kernel is the fused fast path: a grid step's block of whole rows, all
+K client slots of each, runs the whole fixed-trip price->frequency
+bisection in VMEM/VREGs and emits BOTH the per-service demand b_n(lam) and
+its closed-form slope db_n/dlam (Lemma 1 /
 Eqns. 9-10 via psi(f) = f'/(1+f)) in a single launch, so a safeguarded-Newton
 dual iteration (``disba.solve_lambda_newton_warm``) is one kernel call
 instead of ~48 jnp sweeps.  Zero HBM traffic beyond the initial tile load --
@@ -22,9 +23,10 @@ compute-bound on the VPU like its sibling ``bisect_alloc``.
 
 Tiling/padding conventions match ``bisect_alloc``: padded client slots carry
 alpha = 0 (zero contribution to every sum), K is padded to the 128-lane
-multiple, N to the tile.  Rows with sum(alpha) = 0 (inactive fixed-capacity
-slots) and opted-out providers (lam >= p_max = 1/sum(alpha)) emit
-b = slope = 0.
+multiple, N to the tile, whose rows ``row_blocks`` fits to K: the
+block's ``VMEM_PLANES`` planes of K lanes fit the VMEM budget.  Rows with
+sum(alpha) = 0 (inactive fixed-capacity slots) and opted-out providers
+(lam >= p_max = 1/sum(alpha)) emit b = slope = 0.
 """
 from __future__ import annotations
 
@@ -34,11 +36,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.tiling import fold_rows, padded_shape, row_tile
+from repro.kernels import tiling
 
 NEG_INF = -1e30
 TINY = 1e-30
 F_CEIL = 1.0 - 1e-6  # stay strictly inside the 1 - tC*f > 0 region (Eq. 14)
+# (block, K) float32 planes a grid step holds in VMEM: alpha and t^C in,
+# double-buffered (4), and the temporaries of the bisection and the slope
+# sums (5).  The TPU v5e compiler takes 8.3 planes at 1792 lanes.
+VMEM_PLANES = 9
+
+
+def row_blocks(rows: int, k: int) -> tiling.Launch:
+    """The launch ``dual_demand`` makes over ``rows`` rows of ``k`` client
+    slots (a vmapped call's rows are the whole batch's): its padded
+    rows and lanes, grid step and VMEM bytes a step."""
+    return tiling.row_blocks(rows, k, VMEM_PLANES)
 
 
 def demand_slope_tile(alpha, tcomp, lam, iters: int):
@@ -118,13 +131,13 @@ def dual_demand(
     (``tiling.fold_rows``)."""
     lam = jnp.broadcast_to(jnp.asarray(lam, jnp.float32), alpha.shape[:1])
     launch = functools.partial(_launch, iters=iters, interpret=interpret)
-    return fold_rows(launch)(alpha, t_comp, lam)
+    return tiling.fold_rows(launch)(alpha, t_comp, lam)
 
 
 def _launch(alpha, t_comp, lam, *, iters: int, interpret: bool):
     n, k = alpha.shape
-    n_pad, k_pad = padded_shape(n, k)
-    tile_n = row_tile(n)
+    blocks = row_blocks(n, k)
+    n_pad, k_pad, tile_n, _ = blocks
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))
@@ -147,6 +160,7 @@ def _launch(alpha, t_comp, lam, *, iters: int, interpret: bool):
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
+        compiler_params=blocks.compiler_params,
         interpret=interpret,
     )(alpha.astype(jnp.float32), t_comp.astype(jnp.float32),
       lam.astype(jnp.float32)[:, None])

@@ -34,7 +34,8 @@ delegates to the reference solver itself.
 ``mbdf_demand`` moves the auction's joint (N, M) ``fairness.mbdf_grid``
 bisection onto the same tiling conventions: grid (n_tiles,), each launch
 step solving all M price columns of a (tile, M) block against its
-(tile, K) service block, so services stream from HBM once, not M times.
+(tile, K) service block, so services stream from HBM once, not M times;
+the tile's rows follow K (``mbdf_row_blocks``).
 
 Padding conventions match ``bisect_alloc``/``dual_demand``: padded client
 slots carry alpha = 0, K pads to the 128-lane multiple, N to the tile.
@@ -53,9 +54,20 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.dual_demand import (
     F_CEIL, NEG_INF, TINY, demand_slope_tile,
 )
-from repro.kernels.tiling import fold_rows, padded_shape, row_tile
+from repro.kernels import tiling
 
 TILE_N = 128      # row tile of the megakernel's internal loop
+# (block, K) float32 planes a grid step of ``mbdf_demand`` holds in VMEM:
+# alpha and t^C in, double-buffered (4), and a trip's temporaries (3).
+# The TPU v5e compiler takes 6.4-6.8 planes at 1792 and 4096 lanes.
+MBDF_VMEM_PLANES = 7
+
+
+def mbdf_row_blocks(rows: int, k: int) -> tiling.Launch:
+    """The launch ``mbdf_demand`` makes over ``rows`` rows of ``k`` client
+    slots (a vmapped call's rows are the whole batch's): its padded rows
+    and lanes, grid step and VMEM bytes a step."""
+    return tiling.row_blocks(rows, k, MBDF_VMEM_PLANES)
 
 
 def _freq_tile(alpha, tcomp, b, iters: int):
@@ -173,7 +185,7 @@ def market_clear(
     """One fused launch of the whole market clear.  Returns (b (N,), f (N,),
     lam ())."""
     n, k = alpha.shape
-    n_pad, k_pad = padded_shape(n, k, tile_n)
+    n_pad, k_pad = tiling.padded_shape(n, k, tile_n)
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))
@@ -275,15 +287,15 @@ def mbdf_demand(
     """
     launch = functools.partial(_mbdf_launch, alpha_fair=alpha_fair,
                                iters=iters, interpret=interpret)
-    return fold_rows(launch)(alpha, t_comp, prices)
+    return tiling.fold_rows(launch)(alpha, t_comp, prices)
 
 
 def _mbdf_launch(alpha, t_comp, prices, *, alpha_fair: float, iters: int,
                  interpret: bool):
     n, k = alpha.shape
     m = prices.shape[1]
-    n_pad, k_pad = padded_shape(n, k)
-    tile_n = row_tile(n)
+    blocks = mbdf_row_blocks(n, k)
+    n_pad, k_pad, tile_n, _ = blocks
     if (n_pad, k_pad) != (n, k):
         alpha = jnp.pad(alpha, ((0, n_pad - n), (0, k_pad - k)))
         t_comp = jnp.pad(t_comp, ((0, n_pad - n), (0, k_pad - k)))
@@ -299,6 +311,7 @@ def _mbdf_launch(alpha, t_comp, prices, *, alpha_fair: float, iters: int,
         ],
         out_specs=pl.BlockSpec((tile_n, m), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, m), jnp.float32),
+        compiler_params=blocks.compiler_params,
         interpret=interpret,
     )(alpha.astype(jnp.float32), t_comp.astype(jnp.float32),
       prices.astype(jnp.float32))

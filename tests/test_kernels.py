@@ -130,7 +130,7 @@ def test_padded_shape_is_what_each_wrapper_launches(kernel, n, k, batch):
     from jaxpr_shapes import pallas_launches
 
     from repro.kernels import dual_demand, market_clear
-    from repro.kernels.tiling import padded_shape, row_tile
+    from repro.kernels.tiling import padded_shape
 
     lead = () if batch is None else (batch,)
     a = jnp.ones(lead + (n, k), jnp.float32)
@@ -150,25 +150,67 @@ def test_padded_shape_is_what_each_wrapper_launches(kernel, n, k, batch):
         per_set, lanes = padded_shape(n, k, market_clear.TILE_N)
         want = ((batch or 1) * per_set, lanes, per_set)
     else:
-        want = padded_shape(rows, k) + (row_tile(rows),)
+        want = _launch(kernel, rows, k)
     assert pallas_launches(fn, a) == [want]
 
 
-def test_row_tile_pads_under_a_sublane_group_per_grid_step():
-    """The fewest grid steps of at most ``ROW_CAP`` rows, split evenly:
-    every step pads under 8 rows, and a launch over whole blocks pads
-    none."""
-    from repro.kernels.tiling import ROW_CAP, padded_shape, row_tile
+def _row_blocks(kernel):
+    """The launch rule of a folded kernel, as its wrapper takes it."""
+    from repro.kernels import dual_demand, market_clear
+    from repro.kernels import bisect_alloc as bisect_mod
 
-    for rows in list(range(1, 2 * ROW_CAP + 20)) + [640, 4096, 8256]:
-        tile = row_tile(rows)
-        padded, _ = padded_shape(rows, 1)
-        steps = padded // tile
-        assert tile % 8 == 0 and tile <= ROW_CAP
-        assert steps == -(-rows // ROW_CAP)
-        assert 0 <= padded - rows < 8 * steps
-    assert row_tile(10) == 16 and padded_shape(10, 48) == (16, 128)
-    assert padded_shape(640, 48)[0] == 640
+    return {"bisect_alloc": bisect_mod.row_blocks,
+            "dual_demand": dual_demand.row_blocks,
+            "mbdf_demand": market_clear.mbdf_row_blocks}[kernel]
+
+
+def _launch(kernel, rows, k):
+    """(rows, lanes, row block) the kernel's wrapper launches on."""
+    return tuple(_row_blocks(kernel)(rows, k)[:3])
+
+
+def test_row_tile_pads_under_a_sublane_group_per_grid_step():
+    """The fewest grid steps of at most ``ROW_CAP`` rows whose block fits
+    the VMEM budget, split evenly: every step pads under 8 rows, and a
+    launch over whole blocks pads none.  Up to 128 lanes the cap binds
+    (320 rows a step at 640 rows, the sweep cells' launch), for each
+    kernel's VMEM planes; wider, the block fits the budget, and a launch
+    takes more than one step where one block of all its rows would not."""
+    from repro.kernels.tiling import (ROW_CAP, SUBLANES, VMEM_BUDGET,
+                                      padded_shape)
+
+    for blocks in map(_row_blocks, FOLDED):
+        for k in (1, 48, 128):
+            for rows in list(range(1, 2 * ROW_CAP + 20)) + [640, 4096, 8256]:
+                padded, lanes, tile, _ = blocks(rows, k)
+                steps = padded // tile
+                assert tile % 8 == 0 and tile <= ROW_CAP
+                assert steps == -(-rows // ROW_CAP)
+                assert 0 <= padded - rows < 8 * steps
+                assert lanes == -(-k // 128) * 128
+        assert blocks(640, 48).tile == 320
+        assert blocks(10, 48)[:3] == (16, 128, 16)
+        for k in (384, 1000, 1792, 4096, 8192):
+            for rows in (8, 160, 640, 1000, 2560):
+                padded, _, tile, step_bytes = blocks(rows, k)
+                row_bytes = step_bytes // tile   # a block's bytes a row
+                steps = padded // tile
+                assert tile % SUBLANES == 0 and tile <= ROW_CAP
+                assert step_bytes <= VMEM_BUDGET
+                assert 0 <= padded - rows < 8 * steps
+                one_block = -(-rows // SUBLANES) * SUBLANES
+                if one_block * row_bytes > VMEM_BUDGET:
+                    assert steps > 1
+                # One step fewer would pass the cap or the budget.
+                if steps > 1:
+                    per_step = -(-rows // (steps - 1))
+                    fewer = -(-per_step // SUBLANES) * SUBLANES
+                    assert (fewer > ROW_CAP
+                            or fewer * row_bytes > VMEM_BUDGET)
+        # Past the budget at even one plane, so at every kernel's planes.
+        with pytest.raises(ValueError, match="VMEM budget"):
+            blocks(8, VMEM_BUDGET // (4 * SUBLANES) + 128)
+    assert padded_shape(10, 48, 16) == (16, 128)
 
 
 def _services(rng, lead, n, k):
@@ -230,16 +272,13 @@ def test_vmapped_wrapper_is_bitwise_a_loop_of_unbatched_calls(kernel, e, n,
     the folded padding of all e * n rows."""
     from jaxpr_shapes import pallas_launches
 
-    from repro.kernels.tiling import padded_shape, row_tile
-
     fn = _folded_kernel(kernel)
     alpha, t_comp, third = _services(np.random.default_rng(e * n + k), (e,),
                                      n, k)
     args = (alpha, t_comp, third[kernel])
     _assert_bitwise(jax.vmap(fn)(*args),
                     _stacked_loop(fn, *args, in_axes=(0, 0, 0)))
-    assert pallas_launches(jax.vmap(fn), *args) == [
-        padded_shape(e * n, k) + (row_tile(e * n),)]
+    assert pallas_launches(jax.vmap(fn), *args) == [_launch(kernel, e * n, k)]
 
 
 @pytest.mark.parametrize("kernel", FOLDED)
@@ -270,16 +309,39 @@ def test_nested_vmap_folds_level_by_level_into_one_launch(kernel):
     one, so the call is still one launch over every row."""
     from jaxpr_shapes import pallas_launches
 
-    from repro.kernels.tiling import padded_shape, row_tile
-
     fn = _folded_kernel(kernel)
     alpha, t_comp, third = _services(np.random.default_rng(6), (2, 3), 10, 48)
     args = (alpha, t_comp, third[kernel])
     nested = jax.vmap(jax.vmap(fn))
     want = _stacked_loop(jax.vmap(fn), *args, in_axes=(0, 0, 0))
     _assert_bitwise(nested(*args), want)
-    assert pallas_launches(nested, *args) == [
-        padded_shape(60, 48) + (row_tile(60),)]
+    assert pallas_launches(nested, *args) == [_launch(kernel, 60, 48)]
+
+
+@pytest.mark.parametrize("kernel", FOLDED)
+def test_a_launch_split_by_the_vmem_budget_is_bitwise_one_block(kernel,
+                                                                monkeypatch):
+    """At 1792 lanes the budget splits 500 rows (under ``ROW_CAP``) into
+    two grid steps; each row's outputs are bitwise what one block of all
+    the rows gives them (a budget large enough for one step,
+    interpreted)."""
+    from jaxpr_shapes import pallas_launches
+
+    from repro.kernels import tiling
+
+    fn = _folded_kernel(kernel)
+    alpha, t_comp, third = _services(np.random.default_rng(7), (), 500, 1792)
+    args = (alpha, t_comp, third[kernel])
+    (rows, _, tile), = pallas_launches(fn, *args)
+    assert rows // tile == 2
+    split = fn(*args)
+    monkeypatch.setattr(tiling, "VMEM_BUDGET", 1 << 30)
+    jax.clear_caches()
+    (rows, _, tile), = pallas_launches(fn, *args)
+    assert rows == tile
+    _assert_bitwise(split, fn(*args))
+    monkeypatch.undo()
+    jax.clear_caches()
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jaxpr_shapes import pallas_launches
+from jaxpr_shapes import kernel_launches, pallas_launches
 
 from repro import obs
 from repro.compat import flat_mesh
@@ -143,6 +143,43 @@ def test_launch_shape_is_what_the_policy_step_launches(policy, warm,
         assert {got + (tile,)} == launches
     else:
         assert got == (batch * n, k) and tile is None
+
+
+@pytest.mark.parametrize("policy,warm", [("selfish", False), ("coop", True),
+                                         ("es", False)])
+def test_launch_at_a_wide_client_axis_is_the_kernel_with_the_largest_block(
+        policy, warm):
+    """At 4096 lanes each kernel's row block fits its own VMEM planes, so
+    the auction's ``mbdf_demand`` and ``bisect_alloc`` take different
+    blocks.  ``launch_shape`` and
+    ``launch_tile`` are the traced launch whose grid step holds the most
+    VMEM bytes, and ``launch_block_bytes`` those bytes."""
+    from repro.kernels import bisect_alloc, dual_demand, market_clear, tiling
+
+    rule = {"_bisect_kernel": bisect_alloc.row_blocks,
+            "_dual_demand_kernel": dual_demand.row_blocks,
+            "_mbdf_kernel": market_clear.mbdf_row_blocks}
+    n, k, batch = 10, 4096, simulator.FLEET_CHUNK
+    pol = policy_mod.get_stateful_policy(policy, warm_start=warm,
+                                         intra_backend="pallas")
+    ones = jnp.ones((batch, n, k), jnp.float32)
+    svc = ServiceSet(alpha=ones, t_comp=ones, mask=ones > 0)
+    step = jax.vmap(lambda s: pol.step(s, jnp.float32(10.0),
+                                       pol.init_state(n)))
+    launches = []
+    for name, traced in kernel_launches(step, svc).items():
+        launch = rule[name](batch * n, k)
+        assert launch[:3] == traced
+        launches.append(launch)
+    shape = dict(intra_backend="pallas", warm_start=warm, n=n, k=k,
+                 batch=batch)
+    widest = max(launches, key=lambda launch: launch.block_bytes)
+    assert policy_mod.launch_shape(policy, **shape) + (
+        policy_mod.launch_tile(policy, **shape),) == widest[:3]
+    assert policy_mod.launch_block_bytes(policy, **shape) == \
+        widest.block_bytes <= tiling.VMEM_BUDGET
+    if policy == "selfish":
+        assert len({launch.tile for launch in launches}) == 2
 
 
 def _recount(cfg, seeds, chunk, n_dev=1):

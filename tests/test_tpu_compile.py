@@ -88,3 +88,23 @@ def test_vmapped_launch_compiles_as_one_kernel_at_the_sweep_cell(one_chip,
         last = (e, n)
     text = _compiled_text(fn, one_chip, (e, n, k), (e, n, k), last)
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("lanes", [1792, 4096])
+@pytest.mark.parametrize("kernel", ["mbdf_demand", "bisect_alloc",
+                                    "dual_demand"])
+def test_wide_client_axis_compiles_in_blocks_that_fit_vmem(one_chip, kernel,
+                                                           lanes):
+    """A sweep chunk's 640 folded rows of a cross-device cohort's client
+    axis (1792 lanes, the xdevice cell's ``k_max``, and 4096): the row
+    tile follows the lanes (``tiling.row_tile``), and a launch whose
+    blocks pass the default scoped VMEM asks for ``tiling.VMEM_LIMIT``,
+    so each grid step fits what the kernel has."""
+    rows = 640
+    fn, last = {
+        "mbdf_demand": (lambda a, t, p: mbdf_demand(a, t, p, 0.5), (rows, 5)),
+        "bisect_alloc": (lambda a, t, b: bisect_alloc(a, t, b), (rows,)),
+        "dual_demand": (lambda a, t, lam: dual_demand(a, t, lam), (rows,)),
+    }[kernel]
+    text = _compiled_text(fn, one_chip, (rows, lanes), (rows, lanes), last)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
